@@ -46,7 +46,7 @@ def bench_kernels(iters: int = 5):
     words = jnp.asarray(rng.integers(0, 2**32, (256, 1024), np.uint32))
     us_ref = _t(jax.jit(ref.crc32_words), words)
     n_bytes = words.size * 4
-    model_us = n_bytes / constants.HBM_BW * 1e6 + 5
+    model_us = n_bytes / constants.peaks().hbm_bw * 1e6 + 5
     rows.append(("kernel.crc32.ref_cpu", us_ref,
                  f"{n_bytes/1e6:.1f}MB;tpu_model={model_us:.1f}us"))
     us_pallas = _t(lambda w: crc32.crc32_blocks(w, interpret=True),
@@ -79,7 +79,7 @@ def bench_kernels(iters: int = 5):
     rows_arr = jnp.asarray(rng.integers(0, 2**32, (16384, 6), np.uint32))
     us_ref = _t(jax.jit(lambda r: ref.sort_tuples(r, 6)), rows_arr)
     sort_bytes = rows_arr.size * 4
-    model_us = (17 * 18 / 2) * sort_bytes / constants.HBM_BW * 1e6  # stages
+    model_us = (17 * 18 / 2) * sort_bytes / constants.peaks().hbm_bw * 1e6  # stages
     rows.append(("kernel.sort.xla_cpu", us_ref,
                  f"16k-rows;tpu_bitonic_model={model_us:.0f}us"))
     us_pallas = _t(lambda r: bitonic_sort.bitonic_sort(

@@ -35,6 +35,7 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from repro.launch.compile_cache import enable_compile_cache
 
 # a zipfian read working set this small must live almost entirely in the
 # block cache; a hit rate below this floor means the cache (or its
@@ -207,6 +208,7 @@ def compare(baseline_path: str, current_path: str, *, threshold: float,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     ap_e = sub.add_parser("emit", help="run smoke benches, write JSON")

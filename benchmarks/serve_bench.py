@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.formats import SSTGeometry
 from repro.core.scheduler import SchedulerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lsm.db import DBConfig, LsmDB
 from repro.lsm.sharded import ShardedDB, uniform_boundaries
 from repro.serving.session_store import (LsmSessionStore, MemorySessionStore)
@@ -186,6 +187,7 @@ def measure_resume(backend: str = "lsm", engine: str = "cpu", *,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--backend", default="lsm",
                     choices=("memory", "lsm", "sharded"))

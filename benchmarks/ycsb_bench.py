@@ -30,6 +30,7 @@ from benchmarks.contention import MeasuredRun, simulate
 from repro.configs.luda_paper import bench_geometry
 from repro.core.scheduler import SchedulerConfig
 from repro.data.ycsb import WorkloadSpec, YCSBWorkload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lsm.db import DBConfig, LsmDB
 
 ENGINES = {
@@ -699,6 +700,7 @@ def _export_obs(args, metrics, tracer, exact_p99_us=None, op=None) -> bool:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--engine", default="device", choices=["device", "cpu"])
     ap.add_argument("--async", dest="async_mode", action="store_true",

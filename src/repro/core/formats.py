@@ -48,6 +48,11 @@ class SSTGeometry:
 
     def __post_init__(self):
         assert self.key_bytes % 4 == 0 and self.value_bytes % 4 == 0
+        # the prefix-encode kernel lays rows out 128 to a lane row, so a
+        # restart interval must divide 128 (checked here for both engines)
+        if self.restart_interval < 1 or 128 % self.restart_interval:
+            raise ValueError(f"restart_interval={self.restart_interval} "
+                             "must divide 128")
 
     @property
     def key_lanes(self) -> int:

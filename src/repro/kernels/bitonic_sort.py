@@ -68,8 +68,7 @@ def bitonic_sort(rows: jax.Array, *,
     compaction batches up to ~2^17 rows; larger sorts use the XLA path in
     ``ops.sort_tuples``).
     """
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "bitonic_sort")
     n, lanes = rows.shape
     n_pad = 1 << max(1, (n - 1).bit_length())
     if n_pad != n:

@@ -1,13 +1,15 @@
-"""Bloom-filter construction Pallas kernel (phase 3 ``filter`` kernel).
+"""Bloom-filter Pallas kernels: construction (phase 3 ``filter`` kernel)
+and the batched read path's pairwise probe.
 
 LUDA's ``filter`` CUDA kernels build one bloom block per SST.  A bit-scatter
-is pathological on TPU, so the adaptation builds the bitmap as an OR-reduction
-of one-hot word masks: for every (key, probe) we compare its word index
-against a word iota and OR in ``1 << bit`` -- compare/select/OR, all VPU.
+is pathological on TPU, so the adaptation builds the bitmap word by word:
+for every (key, probe) it compares the probe's word index with the word
+and ORs in ``1 << bit`` -- compare/select/OR, all VPU.
 
-Grid: ``(group_tiles, key_chunks)``; the key-chunk axis accumulates into the
-output block across sequential grid steps (TPU grid order), bounding VMEM to
-``tile_groups * chunk_keys * n_words`` words.
+Layout: the groups (filters) of a tile lie along the vector lanes and each
+key lane is its own plane, so every operation is lane-dense and no value
+is ever sliced out of a boolean vector.  The wrappers transpose to and
+from the callers' row-major layout.
 """
 
 from __future__ import annotations
@@ -16,39 +18,33 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import common, ref
 
 
 def _bloom_kernel(keys_ref, valid_ref, out_ref, *, n_probes, n_words):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    keys = keys_ref[...]        # [TG, KC, L] uint32
-    valid = valid_ref[...] != 0  # [TG, KC]
-    h1, h2 = ref.bloom_hashes(keys)  # [TG, KC]
+    lanes = [keys_ref[lane] for lane in range(keys_ref.shape[0])]  # [K, TG]
+    h1, h2 = ref.bloom_hashes_lanes(lanes)
+    valid = valid_ref[...]                                # [K, TG] 0/1
     m_bits = jnp.uint32(n_words * 32)
-    word_iota = jax.lax.broadcasted_iota(jnp.uint32,
-                                         (1, 1, n_words), 2)
-    acc = jnp.zeros((keys.shape[0], n_words), jnp.uint32)
+    widx, bits = [], []
     for i in range(n_probes):
-        pos = (h1 + jnp.uint32(i) * h2) % m_bits          # [TG, KC]
-        widx = (pos >> jnp.uint32(5))[..., None]          # [TG, KC, 1]
-        bit = (pos & jnp.uint32(31))[..., None]
-        hit = (word_iota == widx) & valid[..., None]
-        contrib = jnp.where(hit, jnp.uint32(1) << bit, jnp.uint32(0))
-        acc = acc | jax.lax.reduce(contrib, np.uint32(0),
-                                   jax.lax.bitwise_or, (1,))
-    out_ref[...] = out_ref[...] | acc
+        pos = (h1 + jnp.uint32(i) * h2) % m_bits
+        widx.append(pos >> jnp.uint32(5))
+        bits.append((valid << (pos & jnp.uint32(31))))    # 0 if padded
+    for w in range(n_words):
+        acc = jnp.zeros_like(h1)
+        for i in range(n_probes):
+            acc = acc | jnp.where(widx[i] == jnp.uint32(w), bits[i],
+                                  jnp.uint32(0))
+        out_ref[w:w + 1, :] = common.fold(acc, 0, jnp.bitwise_or)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_words", "n_probes", "group_tile", "key_chunk", "interpret"))
+    "n_words", "n_probes", "group_tile", "interpret"))
 def bloom_build(keys: jax.Array, valid: jax.Array, *, n_words: int,
-                n_probes: int, group_tile: int = 4, key_chunk: int = 256,
+                n_probes: int, group_tile: int = 512,
                 interpret: bool | None = None) -> jax.Array:
     """Build bloom filters on device.
 
@@ -56,108 +52,50 @@ def bloom_build(keys: jax.Array, valid: jax.Array, *, n_words: int,
     ``valid``: uint32/bool ``[groups, keys_per_group]`` (0 = padded slot).
     Returns uint32 ``[groups, n_words]``.
     """
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "bloom_build")
     g, k, lanes = keys.shape
-    tg = min(group_tile, g)
-    kc = min(key_chunk, k)
-    gp, kp = common.round_up(g, tg), common.round_up(k, kc)
-    if (gp, kp) != (g, k):
-        keys = jnp.pad(keys, ((0, gp - g), (0, kp - k), (0, 0)))
-        valid = jnp.pad(valid.astype(jnp.uint32),
-                        ((0, gp - g), (0, kp - k)))
+    tg, gp = common.lane_tile(g, group_tile)
+    keys_t = jnp.transpose(keys.astype(jnp.uint32), (2, 1, 0))  # [L, K, G]
+    valid_t = (valid != 0).astype(jnp.uint32).T                 # [K, G]
+    if gp != g:   # valid=0 -> padded groups build empty filters
+        keys_t = jnp.pad(keys_t, ((0, 0), (0, 0), (0, gp - g)))
+        valid_t = jnp.pad(valid_t, ((0, 0), (0, gp - g)))
     out = pl.pallas_call(
         functools.partial(_bloom_kernel, n_probes=n_probes, n_words=n_words),
-        grid=(gp // tg, kp // kc),
+        grid=(gp // tg,),
         in_specs=[
-            pl.BlockSpec((tg, kc, lanes), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tg, kc), lambda i, j: (i, j)),
+            pl.BlockSpec((lanes, k, tg), lambda i: (0, 0, i)),
+            pl.BlockSpec((k, tg), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tg, n_words), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((gp, n_words), jnp.uint32),
+        out_specs=pl.BlockSpec((n_words, tg), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_words, gp), jnp.uint32),
         interpret=interpret,
-    )(keys.astype(jnp.uint32), valid.astype(jnp.uint32))
-    return out[:g]
-
-
-def _bloom_query_kernel(filters_ref, keys_ref, out_ref, *, n_probes,
-                        n_words):
-    filters = filters_ref[...]   # [TG, W]
-    keys = keys_ref[...]         # [TG, QC, L]
-    h1, h2 = ref.bloom_hashes(keys)  # [TG, QC]
-    m_bits = jnp.uint32(n_words * 32)
-    word_iota = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, n_words), 2)
-    ok = jnp.ones(h1.shape, bool)
-    for i in range(n_probes):
-        pos = (h1 + jnp.uint32(i) * h2) % m_bits          # [TG, QC]
-        widx = (pos >> jnp.uint32(5))[..., None]          # [TG, QC, 1]
-        # gather the probed word as a compare/select/OR-reduce (the same
-        # TPU-friendly trick as the build kernel, in reverse)
-        sel = jnp.where(word_iota == widx, filters[:, None, :],
-                        jnp.uint32(0))
-        word = jax.lax.reduce(sel, np.uint32(0), jax.lax.bitwise_or, (2,))
-        bit = (word >> (pos & jnp.uint32(31))) & jnp.uint32(1)
-        ok = ok & (bit == 1)
-    out_ref[...] = ok.astype(jnp.uint32)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "n_probes", "group_tile", "query_chunk", "interpret"))
-def bloom_query(filters: jax.Array, keys: jax.Array, *, n_probes: int,
-                group_tile: int = 4, query_chunk: int = 256,
-                interpret: bool | None = None) -> jax.Array:
-    """Membership probe on device.  ``filters``: uint32 ``[groups, W]``;
-    ``keys``: uint32 ``[groups, queries, lanes]``.  Returns bool
-    ``[groups, queries]`` (True = maybe present)."""
-    if interpret is None:
-        interpret = common.default_interpret()
-    g, q, lanes = keys.shape
-    n_words = filters.shape[-1]
-    tg = min(group_tile, g)
-    qc = min(query_chunk, q)
-    gp, qp = common.round_up(g, tg), common.round_up(q, qc)
-    if (gp, qp) != (g, q):
-        keys = jnp.pad(keys, ((0, gp - g), (0, qp - q), (0, 0)))
-    if gp != g:
-        filters = jnp.pad(filters, ((0, gp - g), (0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_bloom_query_kernel, n_probes=n_probes,
-                          n_words=n_words),
-        grid=(gp // tg, qp // qc),
-        in_specs=[
-            pl.BlockSpec((tg, n_words), lambda i, j: (i, 0)),
-            pl.BlockSpec((tg, qc, lanes), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((tg, qc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((gp, qp), jnp.uint32),
-        interpret=interpret,
-    )(filters.astype(jnp.uint32), keys.astype(jnp.uint32))
-    return out[:g, :q] != 0
+    )(keys_t, valid_t)
+    return out[:, :g].T
 
 
 def _multi_probe_kernel(filters_ref, keys_ref, out_ref, *, n_probes,
                         n_words):
-    filters = filters_ref[...]   # [TC, W]
-    keys = keys_ref[...]         # [TC, L]
-    h1, h2 = ref.bloom_hashes(keys)  # [TC]
+    lanes = [keys_ref[lane:lane + 1, :] for lane in range(keys_ref.shape[0])]
+    h1, h2 = ref.bloom_hashes_lanes(lanes)                # [1, TC]
     m_bits = jnp.uint32(n_words * 32)
-    word_iota = jax.lax.broadcasted_iota(jnp.uint32,
-                                         (keys.shape[0], n_words), 1)
-    ok = jnp.ones(h1.shape, bool)
+    ok = jnp.ones_like(h1)
     for i in range(n_probes):
-        pos = (h1 + jnp.uint32(i) * h2) % m_bits          # [TC]
-        widx = (pos >> jnp.uint32(5))[:, None]            # [TC, 1]
-        sel = jnp.where(word_iota == widx, filters, jnp.uint32(0))
-        word = jax.lax.reduce(sel, np.uint32(0), jax.lax.bitwise_or, (1,))
-        bit = (word >> (pos & jnp.uint32(31))) & jnp.uint32(1)
-        ok = ok & (bit == 1)
-    out_ref[...] = ok.astype(jnp.uint32)[:, None]
+        pos = (h1 + jnp.uint32(i) * h2) % m_bits
+        widx = pos >> jnp.uint32(5)
+        # gather each candidate's probed word: one select per filter word
+        word = jnp.zeros_like(h1)
+        for w in range(n_words):
+            word = word | jnp.where(widx == jnp.uint32(w),
+                                    filters_ref[w:w + 1, :], jnp.uint32(0))
+        ok = ok & (word >> (pos & jnp.uint32(31)))
+    out_ref[...] = ok & jnp.uint32(1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_probes", "cand_tile",
                                              "interpret"))
 def multi_probe(filters: jax.Array, keys: jax.Array, *, n_probes: int,
-                cand_tile: int = 128,
+                cand_tile: int = 512,
                 interpret: bool | None = None) -> jax.Array:
     """Pairwise membership probe: key row ``i`` against filter row ``i``.
 
@@ -165,25 +103,39 @@ def multi_probe(filters: jax.Array, keys: jax.Array, *, n_probes: int,
     prunes the whole candidate set in a single launch.  ``filters``:
     uint32 ``[C, W]``; ``keys``: uint32 ``[C, lanes]``.  Returns bool
     ``[C]`` (True = maybe present)."""
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "multi_probe")
     c, lanes = keys.shape
     n_words = filters.shape[-1]
-    tc = min(cand_tile, c)
-    cp = common.round_up(c, tc)
+    tc, cp = common.lane_tile(c, cand_tile)
+    filters_t = filters.astype(jnp.uint32).T    # [W, C]
+    keys_t = keys.astype(jnp.uint32).T          # [L, C]
     if cp != c:   # zero filters -> padded rows report absent
-        filters = jnp.pad(filters, ((0, cp - c), (0, 0)))
-        keys = jnp.pad(keys, ((0, cp - c), (0, 0)))
+        filters_t = jnp.pad(filters_t, ((0, 0), (0, cp - c)))
+        keys_t = jnp.pad(keys_t, ((0, 0), (0, cp - c)))
     out = pl.pallas_call(
         functools.partial(_multi_probe_kernel, n_probes=n_probes,
                           n_words=n_words),
         grid=(cp // tc,),
         in_specs=[
-            pl.BlockSpec((tc, n_words), lambda i: (i, 0)),
-            pl.BlockSpec((tc, lanes), lambda i: (i, 0)),
+            pl.BlockSpec((n_words, tc), lambda i: (0, i)),
+            pl.BlockSpec((lanes, tc), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tc, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.uint32),
+        out_specs=pl.BlockSpec((1, tc), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, cp), jnp.uint32),
         interpret=interpret,
-    )(filters.astype(jnp.uint32), keys.astype(jnp.uint32))
-    return out[:c, 0] != 0
+    )(filters_t, keys_t)
+    return out[0, :c] != 0
+
+
+def bloom_query(filters: jax.Array, keys: jax.Array, *, n_probes: int,
+                cand_tile: int = 512,
+                interpret: bool | None = None) -> jax.Array:
+    """Membership probe on device.  ``filters``: uint32 ``[groups, W]``;
+    ``keys``: uint32 ``[groups, queries, lanes]``.  Returns bool
+    ``[groups, queries]`` (True = maybe present).  Every query is paired
+    with its group's filter row and resolved by ``multi_probe``."""
+    g, q, lanes = keys.shape
+    rows = jnp.repeat(filters, q, axis=0)
+    hit = multi_probe(rows, keys.reshape(g * q, lanes), n_probes=n_probes,
+                      cand_tile=cand_tile, interpret=interpret)
+    return hit.reshape(g, q)

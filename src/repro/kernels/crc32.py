@@ -7,8 +7,9 @@ reduction of per-bit operator words -- pure VPU work with no gathers and no
 serial dependence.
 
 Grid: one program per tile of blocks.  Each program loads a ``[TB, W]``
-uint32 tile plus the shared ``[W, 32]`` operator table into VMEM, does 32
-shift/mask/select rounds and one XOR tree.
+uint32 tile plus the shared operator table, stored transposed as
+``[32, W]`` so that bit ``j``'s operator words are one lane-dense row, into
+VMEM, does 32 shift/mask rounds and one XOR halving tree over the lanes.
 """
 
 from __future__ import annotations
@@ -17,7 +18,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import common, tables
@@ -28,15 +28,15 @@ def _crc32_kernel(words_ref, table_ref, out_ref):
     acc = jnp.zeros_like(words)
     for j in range(32):
         bit = (words >> jnp.uint32(j)) & jnp.uint32(1)
-        lane = table_ref[:, j][None, :]  # [1, W]
-        acc = acc ^ jnp.where(bit.astype(bool), lane, jnp.uint32(0))
-    folded = jax.lax.reduce(acc, np.uint32(0), jax.lax.bitwise_xor, (1,))
-    out_ref[...] = folded[:, None]
+        # 0 - bit is all ones where the bit is set: a mask, not a select
+        acc = acc ^ (table_ref[j:j + 1, :] & (jnp.uint32(0) - bit))
+    out_ref[...] = common.fold(acc, 1, jnp.bitwise_xor)  # [TB, 1]
 
 
 def _raw_contrib(words: jax.Array, T: jax.Array, *, block_tile: int,
                  interpret: bool) -> jax.Array:
-    """XOR-fold of per-bit contributions (no final base xor)."""
+    """XOR-fold of per-bit contributions (no final base xor).  ``T`` is
+    the operator table transposed, ``[32, n_words]``."""
     n_blocks, n_words = words.shape
     tb = min(block_tile, n_blocks)
     padded = common.round_up(n_blocks, tb)
@@ -47,7 +47,7 @@ def _raw_contrib(words: jax.Array, T: jax.Array, *, block_tile: int,
         grid=(padded // tb,),
         in_specs=[
             pl.BlockSpec((tb, n_words), lambda i: (i, 0)),
-            pl.BlockSpec((n_words, 32), lambda i: (0, 0)),
+            pl.BlockSpec((32, n_words), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, 1), jnp.uint32),
@@ -65,10 +65,9 @@ def crc32_blocks(words: jax.Array, *, block_tile: int = 8,
     each block's bytes).  Returns uint32 ``[n_blocks]``, bit-exact with
     ``binascii.crc32`` on each row's bytes.
     """
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "crc32")
     n_words = words.shape[1]
-    T = jnp.asarray(tables.crc32_operator_table(n_words))
+    T = jnp.asarray(tables.crc32_operator_table(n_words).T)
     base = jnp.uint32(tables.crc32_zero_message(n_words * 4))
     return _raw_contrib(words, T, block_tile=block_tile,
                         interpret=interpret) ^ base
@@ -88,16 +87,15 @@ def crc32_blocks_sections(sections, *, block_tile: int = 8,
 
     ``sections``: list of uint32 ``[n_blocks, w_i]``.
     """
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "crc32")
     total = sum(s.shape[1] for s in sections)
-    T = jnp.asarray(tables.crc32_operator_table(total))
+    T = jnp.asarray(tables.crc32_operator_table(total).T)
     base = jnp.uint32(tables.crc32_zero_message(total * 4))
     acc = base
     off = 0
     for s in sections:
         w = s.shape[1]
-        acc = acc ^ _raw_contrib(s, T[off:off + w],
+        acc = acc ^ _raw_contrib(s, T[:, off:off + w],
                                  block_tile=block_tile,
                                  interpret=interpret)
         off += w

@@ -5,19 +5,21 @@ wide launch fills the device -- applies to lookups exactly as it does to
 compactions.  ``multi_get`` stacks one *candidate* (query key, decoded SST
 block) pair per row and resolves every one in a single launch:
 
-* **search** -- per candidate, an unrolled binary search over the block's
-  ``K`` sorted key rows.  The row gather at each step is the same
-  TPU-friendly compare/select/OR-reduce used by the bloom kernels (a
-  dynamic row gather is pathological on the VPU); ``log2 K`` steps of
-  ``O(K * L)`` vector work, with K = keys per block (small by geometry).
-* **gather** -- one-hot select of the matched row's meta word and value
-  slot, masked by the found verdict.
+* **rank** -- per candidate, the lower bound of the query among the
+  block's ``K`` sorted key rows, counted as the number of rows that
+  compare below it (the rows are sorted, so this equals a binary search's
+  answer).  All ``K`` rows are compared at once: ``O(K * L)`` vector work
+  with K = keys per block (small by geometry) and no data-dependent row
+  gather, which is pathological on the VPU.
+* **gather** -- a one-hot select of the ranked row's meta word and value
+  slot, OR-folded over the rows and masked by the found verdict.
 
-Same two-stage shape as ``merge_path.py``: a vectorized search producing
-positions, then a windowed gather -- here both stages fit one kernel
-because the window is a single block row.  Grid is 1-D over candidate
-tiles; VMEM per tile is ``TC * K * (L + Vw + 1)`` words, independent of
-the candidate count.
+Layout: candidates lie along the vector lanes, and every key lane and
+value word is its own ``[K, C]`` plane, so every vector is dense and all
+compares and selects are on ``uint32``.  The wrapper transposes to and
+from the callers' row-major layout.  Grid is 1-D over candidate tiles;
+VMEM per tile is ``TC * K * (L + Vw + 1)`` words, independent of the
+candidate count.
 
 Sentinel contract (matches ``merge_path.PAD_WORD``): block rows at or
 beyond ``nvalid`` must hold all-ones keys so the per-block order is total;
@@ -30,7 +32,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import common
@@ -38,95 +39,76 @@ from repro.kernels import common
 PAD_WORD = jnp.uint32(0xFFFFFFFF)
 
 
-def _select_row(keys: jax.Array, onehot: jax.Array) -> jax.Array:
-    """Gather one ``[L]`` row per candidate: ``keys`` ``[TC, K, L]``,
-    ``onehot`` bool ``[TC, K]`` (exactly one hot) -> ``[TC, L]``."""
-    sel = jnp.where(onehot[..., None], keys, jnp.uint32(0))
-    return jax.lax.reduce(sel, np.uint32(0), jax.lax.bitwise_or, (1,))
-
-
 def _lookup_kernel(keys_ref, meta_ref, vals_ref, nvalid_ref, q_ref,
-                   found_ref, meta_out_ref, val_out_ref, *, n_kvs, lanes):
-    keys = keys_ref[...]            # [TC, K, L]
-    meta = meta_ref[...]            # [TC, K]
-    vals = vals_ref[...]            # [TC, K, Vw]
-    nvalid = nvalid_ref[...][:, 0]  # [TC]
-    q = q_ref[...]                  # [TC, L]
-    tc = keys.shape[0]
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (tc, n_kvs), 1)
-
-    lo = jnp.zeros((tc,), jnp.int32)
-    hi = jnp.full((tc,), n_kvs, jnp.int32)
-    for _ in range((n_kvs + 1).bit_length()):
-        go = lo < hi
-        mid = (lo + hi) >> 1          # always in [0, K) while go
-        row = _select_row(keys, iota_k == mid[:, None])
-        descend = common.lex_less(row, q, lanes)       # keys[mid] < q
-        lo = jnp.where(go & descend, mid + 1, lo)
-        hi = jnp.where(go & ~descend, mid, hi)
-
-    idx = jnp.clip(lo, 0, n_kvs - 1)
-    onehot = iota_k == idx[:, None]
-    hit = _select_row(keys, onehot)
-    eq = jnp.ones((tc,), bool)
-    for lane in range(lanes):
-        eq = eq & (hit[:, lane] == q[:, lane])
-    found = eq & (lo < nvalid)
-    m = jax.lax.reduce(jnp.where(onehot, meta, jnp.uint32(0)),
-                       np.uint32(0), jax.lax.bitwise_or, (1,))
-    v = _select_row(vals, onehot)
-    found_ref[...] = found.astype(jnp.uint32)[:, None]
-    meta_out_ref[...] = jnp.where(found, m, jnp.uint32(0))[:, None]
-    val_out_ref[...] = jnp.where(found[:, None], v, jnp.uint32(0))
+                   found_ref, meta_out_ref, val_out_ref):
+    n_lanes, n_kvs, tc = keys_ref.shape
+    zero = jnp.uint32(0)
+    below = jnp.zeros((n_kvs, tc), jnp.int32)   # row < query
+    equal = jnp.ones((n_kvs, tc), jnp.int32)    # row == query so far
+    for lane in range(n_lanes):
+        row = keys_ref[lane]                    # [K, TC]
+        q = q_ref[lane:lane + 1, :]             # [1, TC]
+        below = jnp.maximum(below, equal * (row < q).astype(jnp.int32))
+        equal = equal * (row == q).astype(jnp.int32)
+    rank = jnp.sum(below, axis=0, keepdims=True)              # [1, TC]
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (n_kvs, tc), 0)
+    match = common.fold(jnp.where(iota_k == rank, equal, 0), 0,
+                        jnp.bitwise_or)
+    found = match * (rank < nvalid_ref[...]).astype(jnp.int32)  # [1, TC]
+    pick = iota_k == jnp.where(found != 0, rank, -1)  # <= 1 row per lane
+    found_ref[...] = found.astype(jnp.uint32)
+    meta_out_ref[...] = common.fold(jnp.where(pick, meta_ref[...], zero), 0,
+                                    jnp.bitwise_or)
+    for w in range(vals_ref.shape[0]):
+        val_out_ref[w:w + 1, :] = common.fold(
+            jnp.where(pick, vals_ref[w], zero), 0, jnp.bitwise_or)
 
 
 @functools.partial(jax.jit, static_argnames=("cand_tile", "interpret"))
 def lookup_blocks(keys: jax.Array, meta: jax.Array, vals: jax.Array,
                   nvalid: jax.Array, queries: jax.Array, *,
-                  cand_tile: int = 8, interpret: bool | None = None
+                  cand_tile: int = 512, interpret: bool | None = None
                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Resolve C stacked (query, block) candidates in one launch.
 
     Shapes/contract as ``ref.lookup_blocks`` (rows >= ``nvalid`` must be
     all-ones sentinels).  Returns ``(found bool [C], meta uint32 [C],
     value uint32 [C, Vw])``, meta/value zeroed where not found."""
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "lookup_blocks")
     C, K, L = keys.shape
     Vw = vals.shape[-1]
-    tc = min(cand_tile, C)
-    Cp = common.round_up(C, tc)
-    if Cp != C:
-        pad = Cp - C
-        keys = jnp.pad(keys, ((0, pad), (0, 0), (0, 0)),
-                       constant_values=PAD_WORD)
-        meta = jnp.pad(meta, ((0, pad), (0, 0)))
-        vals = jnp.pad(vals, ((0, pad), (0, 0), (0, 0)))
-        nvalid = jnp.pad(nvalid, (0, pad))     # nvalid=0 -> never found
-        queries = jnp.pad(queries, ((0, pad), (0, 0)))
+    tc, Cp = common.lane_tile(C, cand_tile)
+    keys_t = jnp.transpose(keys.astype(jnp.uint32), (2, 1, 0))   # [L, K, C]
+    meta_t = meta.astype(jnp.uint32).T                           # [K, C]
+    vals_t = jnp.transpose(vals.astype(jnp.uint32), (2, 1, 0))   # [Vw, K, C]
+    nvalid_t = nvalid.astype(jnp.int32).reshape(1, C)
+    q_t = queries.astype(jnp.uint32).T                           # [L, C]
+    if Cp != C:   # nvalid=0 -> padded candidates are never found
+        pad = ((0, 0),) * 2 + ((0, Cp - C),)
+        keys_t = jnp.pad(keys_t, pad, constant_values=PAD_WORD)
+        vals_t = jnp.pad(vals_t, pad)
+        meta_t, nvalid_t, q_t = (jnp.pad(a, ((0, 0), (0, Cp - C)))
+                                 for a in (meta_t, nvalid_t, q_t))
     found, m, v = pl.pallas_call(
-        functools.partial(_lookup_kernel, n_kvs=K, lanes=L),
+        _lookup_kernel,
         grid=(Cp // tc,),
         in_specs=[
-            pl.BlockSpec((tc, K, L), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tc, K), lambda i: (i, 0)),
-            pl.BlockSpec((tc, K, Vw), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tc, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tc, L), lambda i: (i, 0)),
+            pl.BlockSpec((L, K, tc), lambda i: (0, 0, i)),
+            pl.BlockSpec((K, tc), lambda i: (0, i)),
+            pl.BlockSpec((Vw, K, tc), lambda i: (0, 0, i)),
+            pl.BlockSpec((1, tc), lambda i: (0, i)),
+            pl.BlockSpec((L, tc), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((tc, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tc, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tc, Vw), lambda i: (i, 0)),
+            pl.BlockSpec((1, tc), lambda i: (0, i)),
+            pl.BlockSpec((1, tc), lambda i: (0, i)),
+            pl.BlockSpec((Vw, tc), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Cp, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((Cp, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((Cp, Vw), jnp.uint32),
+            jax.ShapeDtypeStruct((1, Cp), jnp.uint32),
+            jax.ShapeDtypeStruct((1, Cp), jnp.uint32),
+            jax.ShapeDtypeStruct((Vw, Cp), jnp.uint32),
         ],
         interpret=interpret,
-    )(keys.astype(jnp.uint32), meta.astype(jnp.uint32),
-      vals.astype(jnp.uint32),
-      nvalid.astype(jnp.int32).reshape(Cp, 1),
-      queries.astype(jnp.uint32))
-    return found[:C, 0] != 0, m[:C, 0], v[:C]
+    )(keys_t, meta_t, vals_t, nvalid_t, q_t)
+    return found[0, :C] != 0, m[0, :C], v[:, :C].T

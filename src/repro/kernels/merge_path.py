@@ -13,7 +13,7 @@ Two-stage merge path (ModernGPU-style):
   merged prefix has that length.  Vectorized over all diagonals (one XLA
   gather per search step).
 * **merge** -- one grid cell per output chunk.  Scalar-prefetched splits
-  drive unblocked index maps, so each cell DMAs only its two ``chunk``-row
+  drive element-offset index maps, so each cell DMAs only its two ``chunk``-row
   windows into VMEM and serially merges an equal-size chunk.  VMEM per cell
   is ``3 * chunk * lanes`` words regardless of n, which removes the bitonic
   path's single-block 2^17-row cap.
@@ -37,6 +37,8 @@ from repro.kernels import common
 
 # Sentinel rows sort after all real rows (matches bitonic_sort.PAD_WORD).
 PAD_WORD = jnp.uint32(0xFFFFFFFF)
+# rows per sublane tile: windows must start on a tile boundary
+_TILE = 8
 
 
 def rows_sorted(rows: np.ndarray) -> bool:
@@ -87,31 +89,41 @@ def _partition(a_p: jax.Array, b_p: jax.Array, na: int, nb: int,
 def _merge_kernel(starts_ref, a_ref, b_ref, out_ref, *, chunk, lanes):
     """Serially merge one equal-size output chunk from two VMEM windows.
 
-    The windows start exactly at this cell's merge-path split, so the first
+    Each window begins at the 8-row tile boundary at or below this cell's
+    merge-path split and holds ``chunk + 8`` rows, so the merge starts at
+    the split's offset inside its tile.  From the split, the first
     ``chunk`` picks of a bounds-free two-way merge are exactly output rows
     ``[g*chunk, (g+1)*chunk)``; window overruns hit sentinel rows, which
-    compare greater than everything real."""
-    del starts_ref  # consumed by the index maps
-    a = a_ref[...]
-    b = b_ref[...]
+    compare greater than everything real.  Rows compare as whole
+    ``[1, lanes]`` vectors: the first differing lane (a min over a lane
+    iota) decides, and ties go to ``a``."""
+    g = pl.program_id(0)
+    sa = starts_ref[g]
+    sb = g * chunk - sa
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
 
     def body(t, carry):
         ia, ib = carry
-        a_row = jax.lax.dynamic_slice(a, (ia, 0), (1, lanes))[0]
-        b_row = jax.lax.dynamic_slice(b, (ib, 0), (1, lanes))[0]
-        take_a = jnp.logical_not(common.lex_less(b_row, a_row, lanes))
-        out_ref[pl.ds(t, 1), :] = jnp.where(take_a, a_row, b_row)[None]
-        ta = take_a.astype(jnp.int32)
-        return ia + ta, ib + (1 - ta)
+        a_row = a_ref[pl.ds(ia, 1), :]
+        b_row = b_ref[pl.ds(ib, 1), :]
+        first = jnp.min(jnp.where(a_row != b_row, lane, lanes))
+        take_b = jnp.max(jnp.where(lane == first,
+                                   (b_row < a_row).astype(jnp.int32), 0))
+        out_ref[pl.ds(t, 1), :] = jnp.where(take_b == 1, b_row, a_row)
+        return ia + 1 - take_b, ib + take_b
 
-    jax.lax.fori_loop(0, chunk, body, (jnp.int32(0), jnp.int32(0)))
+    jax.lax.fori_loop(0, chunk, body, (sa % _TILE, sb % _TILE))
+
+
+def _window(split):
+    """Element offset of a window: the tile boundary at or below ``split``."""
+    return pl.multiple_of(split // _TILE * _TILE, _TILE)
 
 
 def merge_sorted(a: jax.Array, b: jax.Array, *, chunk: int = 256,
                  interpret: bool | None = None) -> jax.Array:
     """Merge two sorted uint32 row arrays on device via merge path."""
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "merge_path")
     na, nb = a.shape[0], b.shape[0]
     lanes = a.shape[1]
     if na == 0:
@@ -120,7 +132,8 @@ def merge_sorted(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         return a
     total = na + nb
     n_chunks = -(-total // chunk)
-    pad = jnp.full((chunk, lanes), PAD_WORD, jnp.uint32)
+    win = chunk + _TILE
+    pad = jnp.full((win, lanes), PAD_WORD, jnp.uint32)
     a_p = jnp.concatenate([a.astype(jnp.uint32), pad], axis=0)
     b_p = jnp.concatenate([b.astype(jnp.uint32), pad], axis=0)
     starts = _partition(a_p, b_p, na, nb, n_chunks, chunk)
@@ -128,10 +141,10 @@ def merge_sorted(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         num_scalar_prefetch=1,
         grid=(n_chunks,),
         in_specs=[
-            pl.BlockSpec((chunk, lanes), lambda g, s: (s[g], 0),
-                         indexing_mode=pl.Unblocked()),
-            pl.BlockSpec((chunk, lanes), lambda g, s: (g * chunk - s[g], 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec((pl.Element(win), pl.Element(lanes)),
+                         lambda g, s: (_window(s[g]), 0)),
+            pl.BlockSpec((pl.Element(win), pl.Element(lanes)),
+                         lambda g, s: (_window(g * chunk - s[g]), 0)),
         ],
         out_specs=pl.BlockSpec((chunk, lanes), lambda g, s: (g, 0)),
     )

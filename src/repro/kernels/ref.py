@@ -77,12 +77,19 @@ def _mix32(h: jax.Array) -> jax.Array:
 def bloom_hashes(keys: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Two 32-bit hashes per key. ``keys``: uint32 ``[..., lanes]``."""
     keys = keys.astype(jnp.uint32)
-    h1 = jnp.full(keys.shape[:-1], _FNV_OFFSET, jnp.uint32)
-    h2 = jnp.full(keys.shape[:-1], _FNV_OFFSET ^ jnp.uint32(0xDEADBEEF),
+    return bloom_hashes_lanes([keys[..., lane]
+                               for lane in range(keys.shape[-1])])
+
+
+def bloom_hashes_lanes(lanes: list) -> tuple[jax.Array, jax.Array]:
+    """``bloom_hashes`` over keys given as one uint32 array per key lane
+    (the layout the Pallas kernels hold them in)."""
+    h1 = jnp.full(lanes[0].shape, _FNV_OFFSET, jnp.uint32)
+    h2 = jnp.full(lanes[0].shape, _FNV_OFFSET ^ jnp.uint32(0xDEADBEEF),
                   jnp.uint32)
-    for lane in range(keys.shape[-1]):
-        h1 = (h1 ^ keys[..., lane]) * _FNV_PRIME
-        h2 = (h2 ^ jnp.uint32(0x9E3779B9) ^ keys[..., lane]) * _FNV_PRIME
+    for lane in lanes:
+        h1 = (h1 ^ lane) * _FNV_PRIME
+        h2 = (h2 ^ jnp.uint32(0x9E3779B9) ^ lane) * _FNV_PRIME
     h1 = _mix32(h1)
     h2 = _mix32(h2) | jnp.uint32(1)  # odd delta: full-period double hashing
     return h1, h2

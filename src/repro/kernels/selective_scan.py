@@ -34,8 +34,7 @@ def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
     d_skip = d_ref[...].reshape(-1)   # [dT] (1-D blocks may load as 2-D)
 
     def row(ref, t):
-        return pl.load(ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                             slice(None)))[0, 0]
+        return ref[0, pl.ds(t, 1), :][0]
 
     def step(t, h):
         dt_t = row(dt_ref, t)                        # [dT] fp32
@@ -46,8 +45,7 @@ def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
         dbu = (dt_t * u_t)[:, None] * b_t[None, :]
         h = da * h + dbu
         y = (h * c_t[None, :]).sum(-1) + d_skip * u_t
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y[None, None, :].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y[None, :].astype(y_ref.dtype)
         return h
 
     h0 = h0_ref[...].reshape(a.shape).astype(jnp.float32)  # [dT, ds]
@@ -64,8 +62,7 @@ def selective_scan(u, dt, b, c, a_log, d_skip, h0=None, *,
     h0: optional [B, di, ds] carried state.
     Returns (y [B, S, di] fp32, h_last [B, di, ds] fp32).
     """
-    if interpret is None:
-        interpret = common.default_interpret()
+    interpret = common.resolve_interpret(interpret, "selective_scan")
     bsz, seq, di = u.shape
     ds = b.shape[-1]
     dt_t = min(d_tile, di)

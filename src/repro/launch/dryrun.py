@@ -45,6 +45,7 @@ def _analyze(compiled, mesh, *, seconds, extra):
     bytes_dev = max(float(hlo_traffic_bytes(hlo_txt)["bytes"]),
                     float(ca.get("bytes accessed", 0.0)))
     coll_dev = coll["total_bytes"]
+    chip = constants.peaks()
     rec = {
         "mesh": {"shape": dict(mesh.shape), "devices": n},
         "compile_seconds": seconds,
@@ -55,9 +56,9 @@ def _analyze(compiled, mesh, *, seconds, extra):
             "alias_bytes": ma.alias_size_in_bytes,
             "peak_estimate_bytes": ma.argument_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes,
-            "hbm_per_chip": constants.HBM_PER_CHIP,
+            "hbm_per_chip": chip.hbm_bytes,
             "fits": (ma.argument_size_in_bytes + ma.temp_size_in_bytes
-                     - ma.alias_size_in_bytes) < constants.HBM_PER_CHIP,
+                     - ma.alias_size_in_bytes) < chip.hbm_bytes,
         },
         "cost": {"flops_per_device": flops_dev,
                  "bytes_per_device": bytes_dev,
@@ -68,9 +69,9 @@ def _analyze(compiled, mesh, *, seconds, extra):
                  "dot_flop_stats": dot},
         "collectives": coll,
         "roofline": {
-            "compute_s": flops_dev / constants.PEAK_FLOPS_BF16,
-            "memory_s": bytes_dev / constants.HBM_BW,
-            "collective_s": coll_dev / constants.ICI_LINK_BW,
+            "compute_s": flops_dev / chip.bf16_flops,
+            "memory_s": bytes_dev / chip.hbm_bw,
+            "collective_s": coll_dev / chip.ici_link_bw,
         },
         **extra,
     }

@@ -14,6 +14,7 @@ measured CPU seconds by the effective parallelism of the simulated server
 from __future__ import annotations
 
 import binascii
+import collections
 import dataclasses
 import time
 
@@ -376,6 +377,10 @@ class DeviceCompactionEngine:
         self._cpu = None            # lazy CpuCompactionEngine
         self.fallbacks = 0          # jobs completed via the CPU fallback
         self.launch_retries = 0     # device launches retried before fallback
+        # "<type>: <message>" of the newest launch faults behind the
+        # retries and fallbacks above
+        self.fault_causes: collections.deque[str] = collections.deque(
+            maxlen=64)
 
     def close(self):
         if self._reader is not None:
@@ -396,28 +401,40 @@ class DeviceCompactionEngine:
             self._cpu = CpuCompactionEngine(self.geom, tracer=self.tracer)
         return self._cpu
 
+    def _note_fault(self, cause) -> str:
+        """Keep the cause of a launch fault: an exception or a reason."""
+        text = cause if isinstance(cause, str) else \
+            f"{type(cause).__name__}: {cause}"
+        self.fault_causes.append(text)
+        return text
+
     def _with_fallback(self, attempt, fallback):
-        """Run one compaction job with launch resilience: a failed device
-        attempt (exception or negative CRC verdict) retries once, then
-        the job completes through the CPU engine -- whose output is
-        bit-identical by construction, so degraded mode changes latency,
-        never bytes.  ``SimulatedCrash`` propagates: a process death is
-        not a launch failure.  A genuinely corrupt input fails CRC on the
-        CPU too, so ``apply_compaction``'s inputs-retained abort is
+        """Run one compaction job with launch resilience: a launch fault
+        -- an injected ``FaultInjected`` or a negative CRC verdict --
+        retries once, then the job completes through the CPU engine,
+        whose output is bit-identical by construction, so degraded mode
+        changes latency, never bytes.  Each cause is kept in
+        ``fault_causes`` and on the ``compact.fallback`` span.  Any other
+        exception propagates: a kernel that fails to trace, lower or
+        compile, or a device error, is not a launch fault, and a CPU
+        rerun would hide it.  ``SimulatedCrash`` (process death)
+        propagates too.  A genuinely corrupt input fails CRC on the CPU
+        too, so ``apply_compaction``'s inputs-retained abort is
         preserved, just with an authoritative host verdict."""
+        cause = None
         for is_retry in (False, True):
             if is_retry:
                 self.launch_retries += 1
             try:
                 out, es = attempt()
-                if es.crc_ok:
-                    return out, es
-            except faults.SimulatedCrash:
-                raise
-            except Exception:
-                pass
+            except faults.FaultInjected as e:
+                cause = self._note_fault(e)
+                continue
+            if es.crc_ok:
+                return out, es
+            cause = self._note_fault("negative CRC verdict")
         self.fallbacks += 1
-        with self.tracer.span("compact.fallback", engine="cpu"):
+        with self.tracer.span("compact.fallback", engine="cpu", cause=cause):
             out, es = fallback()
         es.fallback = True
         return out, es
@@ -532,12 +549,11 @@ class DeviceCompactionEngine:
                 results_group = self._compact_batched(
                     [job_imgs[j] for j in idxs], bucket=sig[1],
                     bottom_level=jobs[idxs[0]][1], read_share=read_share)
-            except faults.SimulatedCrash:
-                raise
-            except Exception:
-                # the stacked launch died: isolate by re-running the
+            except faults.FaultInjected as e:
+                # the stacked launch faulted: isolate by re-running the
                 # group's jobs one by one (device retry + CPU fallback
                 # per job), so one bad launch cannot wedge every shard
+                self._note_fault(e)
                 self.launch_retries += 1
                 results_group = None
             if results_group is None:
@@ -730,26 +746,29 @@ def model_sort_seconds(n_rows: int, lanes: int, n_runs: int,
       (the host-side sort time is measured, not modeled).
     """
     from repro.roofline import constants
+    chip = constants.peaks()
     tup = n_rows * lanes * 4
     log_n = max(1, (max(n_rows, 2) - 1).bit_length())
     if sort_mode == "merge":
         levels = max(1, (max(n_runs, 1) - 1).bit_length())
-        return levels * 2 * tup / constants.HBM_BW
+        return levels * 2 * tup / chip.hbm_bw
     if sort_mode == "device":
         stages = log_n * (log_n + 1) // 2
-        return stages * 2 * tup / constants.HBM_BW
+        return stages * 2 * tup / chip.hbm_bw
     if sort_mode == "xla":
-        return log_n * 2 * tup / constants.HBM_BW
-    return 2 * tup / constants.ICI_LINK_BW  # cooperative round trip
+        return log_n * 2 * tup / chip.hbm_bw
+    return 2 * tup / constants.HOST_LINK_BW_ASSUMED  # cooperative round trip
 
 
 def model_device_seconds(bytes_in: int, bytes_out: int,
                          geom: SSTGeometry) -> float:
-    """Roofline model of the TPU-side compaction time (this container has no
-    TPU; constants from the spec: 819 GB/s HBM, 197 TFLOP/s bf16).  The
-    pipeline is memory-bound: ~3 HBM passes (unpack read, sort traffic,
-    pack write) + PCIe-class host link at 50 GB/s for H2D/D2H."""
-    hbm = 819e9
-    link = 50e9
+    """Roofline model of the device-side compaction time, from the peaks
+    of the device kind in ``roofline.constants`` (the modelled v5e when
+    no TPU is attached; an unlisted TPU raises).  The pipeline is
+    memory-bound: ~3 HBM passes (unpack read, sort traffic, pack write)
+    plus the H2D/D2H copies at the assumed host-link rate."""
+    from repro.roofline import constants
+    chip = constants.peaks()
     moved = 3 * (bytes_in + bytes_out)
-    return moved / hbm + (bytes_in + bytes_out) / link + 20e-6
+    return (moved / chip.hbm_bw
+            + (bytes_in + bytes_out) / constants.HOST_LINK_BW_ASSUMED + 20e-6)

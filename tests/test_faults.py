@@ -384,6 +384,54 @@ def test_crc_failure_verdict_falls_back_to_cpu(tmp_path):
     db.close()
 
 
+def test_launch_fault_fallback_records_cause(tmp_path):
+    from repro.obs import Tracer
+    tracer = Tracer()
+    db = LsmDB(str(tmp_path / "db"),
+               fcfg("device", failpoints="engine.launch=raise"),
+               tracer=tracer)
+    _fill(db)
+    faults.FAILPOINTS.clear()
+    assert db.engine.fallbacks >= 1
+    assert db.engine.fault_causes
+    assert all(c.startswith("FaultInjected: ") and "engine.launch" in c
+               for c in db.engine.fault_causes)
+    spans = [e for e in tracer.to_chrome()["traceEvents"]
+             if e["name"] == "compact.fallback"]
+    assert spans and all("engine.launch" in e["args"]["cause"]
+                         for e in spans)
+    db.close()
+
+
+def test_kernel_lowering_error_propagates(tmp_path, monkeypatch):
+    """A kernel that cannot lower is not a launch fault: the device
+    engine raises instead of finishing the job on the CPU."""
+    import numpy as np
+
+    from repro.core import formats
+    from repro.kernels import common
+    from repro.lsm.cpu_engine import (CpuCompactionEngine,
+                                      DeviceCompactionEngine)
+    # a geometry of its own: no compiled pipeline of another test is reused
+    geom = SSTGeometry(key_bytes=16, value_bytes=44, block_bytes=512,
+                       sst_bytes=2048)
+    keys = np.stack([formats.pack_key_bytes(b"key%03d" % i, 16)
+                     for i in range(40)])
+    meta = np.array([(i + 1) << 1 | 1 for i in range(40)], np.uint32)
+    vals = np.stack([formats.pack_value_bytes(b"v%d" % i, 44)
+                     for i in range(40)])
+    cpu = CpuCompactionEngine(geom)
+    images = [cpu.build_image(keys[:20], meta[:20], vals[:20]),
+              cpu.build_image(keys[20:], meta[20:], vals[20:])]
+    # compiled Pallas kernels cannot lower for the CPU backend
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    eng = DeviceCompactionEngine(geom, backend="pallas")
+    with pytest.raises(ValueError, match="interpret mode"):
+        eng.compact(images)
+    assert eng.fallbacks == 0 and eng.launch_retries == 0
+    assert not eng.fault_causes
+
+
 def test_single_launch_fault_absorbed_by_retry(tmp_path):
     db = LsmDB(str(tmp_path / "db"),
                fcfg("device", failpoints="engine.launch=raise:x1"))

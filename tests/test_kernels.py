@@ -102,7 +102,7 @@ def test_bloom_pallas_matches_ref(groups, keys, lanes, n_words, n_probes):
     want = ref.bloom_build(k, n_words=n_words, n_probes=n_probes,
                            valid=valid != 0)
     got = bloom.bloom_build(k, valid, n_words=n_words, n_probes=n_probes,
-                            key_chunk=128, interpret=True)
+                            group_tile=2, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -117,8 +117,7 @@ def test_bloom_query_pallas_matches_ref(groups, n_keys, queries, n_words):
                                      dtype=np.uint32))
     want = np.asarray(ref.bloom_query(filt, probe, n_probes=7))
     got = np.asarray(bloom.bloom_query(filt, probe, n_probes=7,
-                                       group_tile=2, query_chunk=16,
-                                       interpret=True))
+                                       cand_tile=16, interpret=True))
     np.testing.assert_array_equal(got, want)
     # inserted keys must always hit through the kernel path too
     hit = np.asarray(bloom.bloom_query(filt, keys, n_probes=7,
