@@ -238,13 +238,20 @@ def compact(img: SSTImage, *, geom: SSTGeometry, bottom_level: bool = False,
         raise ValueError(
             'sort_mode="merge" requires run_lens (the per-input entry '
             "counts; see formats.concat_images(..., with_runs=True))")
-    up = unpack(img, geom, backend=backend)
-    rows = build_tuples(up)
-    rows_s = sort_phase(rows, sort_mode=sort_mode, backend=backend,
-                        run_lens=run_lens)
-    live = survivor_mask(rows_s, up.valid, geom.key_lanes,
-                         bottom_level=bottom_level)
-    out = pack(rows_s, live, up.vals, geom, backend=backend)
+    # one named scope per phase: the device profile and the HLO op
+    # metadata name each operation's phase
+    with jax.named_scope("unpack"):
+        up = unpack(img, geom, backend=backend)
+    with jax.named_scope("tuples"):
+        rows = build_tuples(up)
+    with jax.named_scope("merge" if sort_mode == "merge" else "sort"):
+        rows_s = sort_phase(rows, sort_mode=sort_mode, backend=backend,
+                            run_lens=run_lens)
+    with jax.named_scope("survivors"):
+        live = survivor_mask(rows_s, up.valid, geom.key_lanes,
+                             bottom_level=bottom_level)
+    with jax.named_scope("pack"):
+        out = pack(rows_s, live, up.vals, geom, backend=backend)
 
     n_in = up.valid.sum()
     n_live = live.sum()

@@ -70,6 +70,7 @@ def bloom_build(keys: jax.Array, valid: jax.Array, *, n_words: int,
         out_specs=pl.BlockSpec((n_words, tg), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n_words, gp), jnp.uint32),
         interpret=interpret,
+        name="bloom_build",
     )(keys_t, valid_t)
     return out[:, :g].T
 
@@ -123,6 +124,7 @@ def multi_probe(filters: jax.Array, keys: jax.Array, *, n_probes: int,
         out_specs=pl.BlockSpec((1, tc), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, cp), jnp.uint32),
         interpret=interpret,
+        name="multi_probe",
     )(filters_t, keys_t)
     return out[0, :c] != 0
 

@@ -34,9 +34,10 @@ def _crc32_kernel(words_ref, table_ref, out_ref):
 
 
 def _raw_contrib(words: jax.Array, T: jax.Array, *, block_tile: int,
-                 interpret: bool) -> jax.Array:
+                 interpret: bool, name: str) -> jax.Array:
     """XOR-fold of per-bit contributions (no final base xor).  ``T`` is
-    the operator table transposed, ``[32, n_words]``."""
+    the operator table transposed, ``[32, n_words]``; ``name`` is the
+    kernel's name on the device (the calling wrapper's)."""
     n_blocks, n_words = words.shape
     tb = min(block_tile, n_blocks)
     padded = common.round_up(n_blocks, tb)
@@ -52,6 +53,7 @@ def _raw_contrib(words: jax.Array, T: jax.Array, *, block_tile: int,
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, 1), jnp.uint32),
         interpret=interpret,
+        name=name,
     )(words.astype(jnp.uint32), T)
     return out[:n_blocks, 0]
 
@@ -70,7 +72,7 @@ def crc32_blocks(words: jax.Array, *, block_tile: int = 8,
     T = jnp.asarray(tables.crc32_operator_table(n_words).T)
     base = jnp.uint32(tables.crc32_zero_message(n_words * 4))
     return _raw_contrib(words, T, block_tile=block_tile,
-                        interpret=interpret) ^ base
+                        interpret=interpret, name="crc32_blocks") ^ base
 
 
 @functools.partial(jax.jit, static_argnames=("block_tile", "interpret"))
@@ -97,6 +99,7 @@ def crc32_blocks_sections(sections, *, block_tile: int = 8,
         w = s.shape[1]
         acc = acc ^ _raw_contrib(s, T[:, off:off + w],
                                  block_tile=block_tile,
-                                 interpret=interpret)
+                                 interpret=interpret,
+                                 name="crc32_blocks_sections")
         off += w
     return acc

@@ -110,5 +110,6 @@ def lookup_blocks(keys: jax.Array, meta: jax.Array, vals: jax.Array,
             jax.ShapeDtypeStruct((Vw, Cp), jnp.uint32),
         ],
         interpret=interpret,
+        name="lookup_blocks",
     )(keys_t, meta_t, vals_t, nvalid_t, q_t)
     return found[0, :C] != 0, m[0, :C], v[:, :C].T

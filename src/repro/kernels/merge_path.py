@@ -153,6 +153,7 @@ def merge_sorted(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_chunks * chunk, lanes), jnp.uint32),
         interpret=interpret,
+        name="merge_runs",
     )(starts, a_p, b_p)
     return out[:total]
 

@@ -71,5 +71,6 @@ def prefix_encode(keys: jax.Array, *, restart_interval: int = 16,
         out_specs=pl.BlockSpec((tr, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, _LANES), jnp.int32),
         interpret=interpret,
+        name="prefix_encode",
     )(keys_t.reshape(lanes, padded, _LANES))
     return out.reshape(-1)[:n]

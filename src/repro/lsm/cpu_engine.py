@@ -360,10 +360,12 @@ class DeviceCompactionEngine:
         self.executor = CompactionExecutor(geom, sort_mode=sort_mode,
                                            backend=backend)
         self._reader = None
-        # shape-bucketed jit cache bookkeeping: every job is padded to a
-        # power-of-two block count, so repeated jobs of similar size reuse
-        # the trace instead of recompiling.  A miss = first job at a bucket.
-        self.jit_bucket_counts: dict[int, int] = {}
+        # jit cache bookkeeping: every job is padded to a power-of-two
+        # block count, so repeated jobs of similar size reuse the trace
+        # instead of recompiling.  The key is the launch's jit signature
+        # (bucket, per-run padded block counts, bottom level, and the job
+        # count of a stacked launch); a miss = first launch of a signature.
+        self.jit_signature_counts: dict[tuple, int] = {}
         self.jit_bucket_hits = 0
         self.jit_bucket_misses = 0
         # batched-launch accounting (compact_many): one "launch" is one
@@ -387,9 +389,9 @@ class DeviceCompactionEngine:
             self._reader.close()
             self._reader = None
 
-    def _note_bucket(self, bucket: int):
-        seen = self.jit_bucket_counts.get(bucket, 0)
-        self.jit_bucket_counts[bucket] = seen + 1
+    def _note_signature(self, sig: tuple):
+        seen = self.jit_signature_counts.get(sig, 0)
+        self.jit_signature_counts[sig] = seen + 1
         if seen:
             self.jit_bucket_hits += 1
         else:
@@ -468,11 +470,14 @@ class DeviceCompactionEngine:
             t0 = time.perf_counter()
             if self._reader is None:
                 self._reader = PrefetchReader()
-            with self.tracer.span("compact.read_inputs", files=len(paths)):
-                imgs, real_blocks = [], 0
+            with self.tracer.span("compact.read_inputs",
+                                  files=len(paths)) as sp:
+                imgs, real_blocks, staged = [], 0, 0
                 for im in self._reader.read_all(paths, sstable.read_sst):
                     real_blocks += im.keys.shape[0]
+                    staged += sum(a.nbytes for a in im)
                     imgs.append(SSTImage(*(jnp.asarray(a) for a in im)))
+                sp.set(h2d_bytes=staged)
             return self._compact_staged(imgs, real_blocks,
                                         bottom_level=bottom_level, t0=t0)
 
@@ -504,9 +509,12 @@ class DeviceCompactionEngine:
         if self._reader is None:
             self._reader = PrefetchReader()
         flat_paths = [p for paths, _ in jobs for p in paths]
-        with self.tracer.span("compact.read_inputs", files=len(flat_paths)):
+        with self.tracer.span("compact.read_inputs",
+                              files=len(flat_paths)) as sp:
             flat_imgs = list(self._reader.read_all(flat_paths,
                                                    sstable.read_sst))
+            # every image read here is staged to the device next
+            sp.set(h2d_bytes=sum(a.nbytes for im in flat_imgs for a in im))
         t_read = time.perf_counter() - t_read0
         job_imgs, job_blocks, off = [], [], 0
         for paths, _ in jobs:
@@ -580,6 +588,7 @@ class DeviceCompactionEngine:
         import jax.numpy as jnp
 
         from repro.core import offload
+        from repro.core.scheduler import batch_signature
         t0 = time.perf_counter()
         staged = []
         for imgs in group_imgs:
@@ -591,19 +600,16 @@ class DeviceCompactionEngine:
                     for im in imgs]
             staged.append(imgs)
         n_jobs = len(staged)
-        self._note_bucket(bucket)
+        self._note_signature(("batch", n_jobs, batch_signature(
+            [im.keys.shape[0] for im in staged[0]], bottom_level,
+            sort_mode=self.executor.sort_mode)))
         self.batch_launches += 1
         self.batch_jobs += n_jobs
         self.max_batch_jobs = max(self.max_batch_jobs, n_jobs)
-        t_exec0 = time.perf_counter()
-        t_exec0_ns = time.perf_counter_ns()
-        faults.fire("engine.launch")
-        outs = self.executor.compact_many(staged, bottom_level=bottom_level,
-                                          pad_blocks=bucket)
-        faults.fire("engine.crc")
-        outs = [(SSTImage(*(np.asarray(a) for a in out)), s)
-                for out, s in outs]
-        exec_wall = time.perf_counter() - t_exec0
+        outs, exec_wall = self._execute(
+            "compact.batch_launch", {"jobs": n_jobs, "bucket": bucket},
+            lambda: self.executor.compact_many(
+                staged, bottom_level=bottom_level, pad_blocks=bucket))
         host_share = max(time.perf_counter() - t0 - exec_wall, 0.0) / n_jobs
         wire = self.geom.wire_words_per_block * 4
         results = []
@@ -622,20 +628,40 @@ class DeviceCompactionEngine:
                 bucket * self.geom.block_kvs, self.geom.key_lanes + 2,
                 n_runs, self.executor.sort_mode)
             results.append((out, stats))
-        if self.tracer.enabled:
-            self.tracer.complete("compact.batch_launch", t_exec0_ns,
-                                 int(exec_wall * 1e9),
-                                 args={"jobs": n_jobs, "bucket": bucket})
-            self._trace_modeled_phases(
-                t_exec0_ns, exec_wall,
-                sum(s.device_seconds for _, s in results),
-                sum(s.sort_seconds for _, s in results),
-                sum(s.bytes_in for _, s in results),
-                sum(s.bytes_out for _, s in results))
         return results
+
+    def _execute(self, span: str, args: dict, launch):
+        """One device launch, traced as ``span`` with three measured,
+        consecutive children: ``compact.dispatch`` (the call into the
+        jitted pipeline: trace, lower, compile or persistent-cache load,
+        enqueue), ``compact.device_wait`` (until the outputs are ready)
+        and ``compact.d2h`` (the output images copied to the host).
+        ``launch()`` returns ``[(image, stats)]``; returns them with host
+        images, and the launch's wall seconds."""
+        import jax
+        t0 = time.perf_counter_ns()
+        faults.fire("engine.launch")
+        t1 = time.perf_counter_ns()
+        res = launch()
+        t2 = time.perf_counter_ns()
+        faults.fire("engine.crc")
+        jax.block_until_ready(res)
+        t3 = time.perf_counter_ns()
+        outs = [(SSTImage(*(np.asarray(a) for a in out)), s)
+                for out, s in res]
+        t4 = time.perf_counter_ns()
+        tr = self.tracer
+        if tr.enabled:
+            tr.complete(span, t0, t4 - t0, args=args)
+            tr.complete("compact.dispatch", t1, t2 - t1)
+            tr.complete("compact.device_wait", t2, t3 - t2)
+            tr.complete("compact.d2h", t3, t4 - t3, args={
+                "bytes": sum(a.nbytes for out, _ in outs for a in out)})
+        return outs, (t4 - t0) / 1e9
 
     def _compact_staged(self, imgs, real_blocks, *, bottom_level, t0):
         from repro.core import offload
+        from repro.core.scheduler import batch_signature
         if self.executor.sort_mode == "merge":
             # run-aligned bucketing: the per-run entry counts are part of
             # the merge pipeline's jit cache key, so pad every input run
@@ -650,18 +676,15 @@ class DeviceCompactionEngine:
         # CRC; the executor appends them as a trailing sentinel run)
         total_blocks = sum(im.keys.shape[0] for im in imgs)
         bucket = offload.next_pow2(total_blocks)
-        self._note_bucket(bucket)
-        # the jitted pipeline call stands in for the TPU execution: its
-        # wall time is NOT host coordination work (the roofline model
-        # supplies the accelerator time) -- time it separately
-        t_exec0 = time.perf_counter()
-        t_exec0_ns = time.perf_counter_ns()
-        faults.fire("engine.launch")
-        out, s = self.executor.compact(imgs, bottom_level=bottom_level,
-                                       pad_blocks=bucket)
-        faults.fire("engine.crc")
-        out = SSTImage(*(np.asarray(a) for a in out))
-        exec_wall = time.perf_counter() - t_exec0
+        self._note_signature(("one", batch_signature(
+            [im.keys.shape[0] for im in imgs], bottom_level,
+            sort_mode=self.executor.sort_mode)))
+        # the launch is device work, not host coordination: its wall time
+        # is kept out of host_seconds
+        [(out, s)], exec_wall = self._execute(
+            "compact.execute", {"jobs": 1, "bucket": bucket},
+            lambda: [self.executor.compact(imgs, bottom_level=bottom_level,
+                                           pad_blocks=bucket)])
         wire = self.geom.wire_words_per_block * 4
         stats = EngineStats(
             n_input=int(s.n_input), n_live=int(s.n_live),
@@ -676,41 +699,7 @@ class DeviceCompactionEngine:
         stats.sort_seconds = model_sort_seconds(
             bucket * self.geom.block_kvs, self.geom.key_lanes + 2,
             n_runs, self.executor.sort_mode)
-        if self.tracer.enabled:
-            self.tracer.complete("compact.execute", t_exec0_ns,
-                                 int(exec_wall * 1e9),
-                                 args={"jobs": 1, "bucket": bucket})
-            self._trace_modeled_phases(
-                t_exec0_ns, exec_wall, stats.device_seconds,
-                stats.sort_seconds, stats.bytes_in, stats.bytes_out)
         return out, stats
-
-    def _trace_modeled_phases(self, t0_ns: int, wall_s: float,
-                              device_s: float, sort_s: float,
-                              bytes_in: int, bytes_out: int):
-        """Nest the roofline-modeled device phases inside the measured
-        launch span: CRC verify -> merge phase 2 -> SST format.  The
-        jitted pipeline call stands in for the accelerator, so the
-        child durations come from the model (their args carry
-        ``modeled: True``), split pro-rata by I/O share and scaled down
-        when the model total exceeds the measured wall so the nesting
-        stays well-formed."""
-        tr = self.tracer
-        io = bytes_in + bytes_out
-        rest = max(device_s - sort_s, 0.0)
-        crc = rest * (bytes_in / io) if io else 0.0
-        phases = (("compact.crc_verify", crc),
-                  ("compact.merge_phase2", max(sort_s, 0.0)),
-                  ("compact.format", rest - crc))
-        total = sum(d for _, d in phases)
-        if total <= 0.0:
-            return
-        scale = min(1.0, wall_s / total)
-        cur = t0_ns
-        for name, d in phases:
-            dur = int(d * scale * 1e9)
-            tr.complete(name, cur, dur, args={"modeled": True})
-            cur += dur
 
     def build_image(self, keys, meta, vals, n_blocks=None) -> SSTImage:
         import jax.numpy as jnp

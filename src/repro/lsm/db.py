@@ -44,7 +44,7 @@ from repro.lsm.memtable import ImmutableMemTable
 from repro.lsm.sstable import BlockCache, FileMeta, TableCache
 from repro.lsm.version import VersionEdit, VersionSet
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, watch_jit
 
 
 @dataclasses.dataclass
@@ -220,6 +220,9 @@ class LsmDB:
                 BackgroundExecutor(workers=1, name="compact")
         else:
             self._flush_exec = self._compact_exec = None
+        # JAX's trace/lower/compile steps as jit.* spans while tracing;
+        # released at close()
+        self._unwatch_jit = watch_jit(self.tracer)
 
     @classmethod
     def open(cls, path: str, cfg: DBConfig | None = None, *,
@@ -1154,6 +1157,7 @@ class LsmDB:
                 self._wal.flush()
                 self._wal.close()
                 self.versions.close()
+            self._unwatch_jit()
 
     def level_sizes(self):
         with self._lock:

@@ -16,11 +16,11 @@ from repro.obs.export import (metrics_json, prometheus_text,
 from repro.obs.metrics import (NULL_REGISTRY, Counter, Gauge, Histogram,
                                MetricsRegistry, NullRegistry,
                                merge_histograms)
-from repro.obs.trace import NULL_TRACER, NullTracer, Tracer
+from repro.obs.trace import NULL_TRACER, NullTracer, Tracer, watch_jit
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NullRegistry",
     "NULL_REGISTRY", "merge_histograms", "Tracer", "NullTracer",
-    "NULL_TRACER", "prometheus_text", "validate_prometheus_text",
+    "NULL_TRACER", "watch_jit", "prometheus_text", "validate_prometheus_text",
     "metrics_json", "write_metrics", "write_prometheus",
 ]

@@ -11,7 +11,8 @@ Reads a Chrome/Perfetto ``trace_event`` JSON file (written by
   span with the largest time overlap (flush build, install, a
   compaction launch, ...) -- "no stall should be unexplained" is the
   point: a p99 spike either lines up with a named background span or
-  shows up here as ``none-active`` (cold start, jit compile, OS noise).
+  shows up here as ``none-active`` (cold start, OS noise; a jit compile
+  is named by its ``jit.*`` span).
 
 See docs/observability.md for a worked example.
 """
@@ -23,7 +24,8 @@ import json
 import sys
 
 # span-name prefixes considered "background work" for stall attribution
-BG_PREFIXES = ("flush.", "compact", "memtable.rotate")
+# (``jit.``: a JAX trace, lower or compile, on any thread)
+BG_PREFIXES = ("flush.", "compact", "memtable.rotate", "jit.")
 
 
 def load_events(path: str) -> list[dict]:

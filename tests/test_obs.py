@@ -179,16 +179,22 @@ def test_prometheus_text_validates():
 
 
 def _golden_tracer() -> Tracer:
-    """Deterministic trace: fake clock, explicit tids."""
-    clock = iter(range(0, 100_000, 500)).__next__
-    tr = Tracer(clock=clock)
+    """Deterministic trace: fake clocks (the profiler's a fixed offset
+    from the tracer's), explicit tids."""
+    ticks = iter(range(0, 100_000, 500))
+    now = [0]
+
+    def clock():
+        now[0] = next(ticks)
+        return now[0]
+    tr = Tracer(clock=clock,
+                profiler_clock=lambda: 1_700_000_000_000_000_000 + now[0])
     with tr.span("db.put", labels="shard=0"):
         with tr.span("memtable.rotate"):
             pass
     tr.complete("compact.execute", 5_000, 4_000,
                 args={"jobs": 2, "bucket": 8}, tid=101)
-    tr.complete("compact.merge_phase2", 5_000, 2_000,
-                args={"modeled": True}, tid=101)
+    tr.complete("compact.dispatch", 5_000, 2_000, tid=101)
     tr.counter("lsm.imm_queue.depth[shard=0]", 1)
     tr.instant("bg_error", {"what": "none"})
     return tr
@@ -205,6 +211,8 @@ def test_perfetto_golden_roundtrip(tmp_path):
     want_meta = [e for e in want["traceEvents"] if e["ph"] == "M"]
     assert [m.get("tid") for m in got_meta] == \
         [m.get("tid") for m in want_meta]
+    sync = [m for m in got_meta if m["name"] == "clock_sync"]
+    assert sync == [m for m in want_meta if m["name"] == "clock_sync"]
     strip = [e for e in doc["traceEvents"] if e["ph"] != "M"]
     assert strip == [e for e in want["traceEvents"] if e["ph"] != "M"]
     # file roundtrip: export -> load -> identical object
@@ -212,6 +220,101 @@ def test_perfetto_golden_roundtrip(tmp_path):
     tr.export(path)
     with open(path) as f:
         assert json.load(f) == doc
+
+
+@pytest.mark.parametrize("drift_ppm", [0.0, 200.0, -350.0])
+def test_clock_mapping_is_linear(drift_ppm):
+    """Every timestamp lands on the profiler's clock through the two
+    readings (creation, export): offset and drift, linear in between."""
+    now = [1_000_000]
+    rate = 1.0 + drift_ppm * 1e-6
+    prof = lambda t: 5_000_000_000 + round(t * rate)  # noqa: E731
+    tr = Tracer(clock=lambda: now[0], profiler_clock=lambda: prof(now[0]))
+    marks = [(2_000_000, 400_000), (3_000_000, 50_000), (7_500_000, 1)]
+    for t, d in marks:
+        tr.complete("s", t, d)
+    now[0] = 10_000_000
+    doc = tr.to_chrome()
+    sync, = [e["args"] for e in doc["traceEvents"]
+             if e["name"] == "clock_sync"]
+    assert sync["origin_ns"] == prof(2_000_000)
+    assert sync["drift_ppm"] == pytest.approx(drift_ppm, abs=1e-6)
+    got = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    for (t, d), e in zip(marks, got):
+        start = sync["origin_ns"] + e["ts"] * 1000
+        assert start == pytest.approx(prof(t), abs=1)
+        assert start + e["dur"] * 1000 == pytest.approx(prof(t + d), abs=1)
+
+
+def test_clock_step_keeps_offset_drops_drift():
+    """A wall clock stepped between the readings is no drift: the export
+    keeps the creation offset and the tracer's own intervals."""
+    now, step = [0], [0]
+    tr = Tracer(clock=lambda: now[0],
+                profiler_clock=lambda: 10**12 + now[0] + step[0])
+    tr.complete("s", 1_000, 2_000)
+    now[0], step[0] = 1_000_000, 3_600 * 10**9
+    doc = tr.to_chrome()
+    sync, = [e["args"] for e in doc["traceEvents"]
+             if e["name"] == "clock_sync"]
+    assert sync == {"clock": "<lambda>", "origin_ns": 10**12 + 1_000,
+                    "drift_ppm": 0.0}
+    span, = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert (span["ts"], span["dur"]) == (0.0, 2.0)
+
+
+def _jit_listeners() -> int:
+    from jax._src import monitoring
+    return len(monitoring.get_event_time_span_listeners())
+
+
+def _compile_fresh(tag: int):
+    """Trace, lower and compile a function JAX has never seen."""
+    import jax
+    import jax.numpy as jnp
+
+    def fresh(x):
+        return x * tag + 1
+    fresh.__name__ = f"fresh_{tag}"
+    jax.jit(fresh)(jnp.arange(3 + tag)).block_until_ready()
+    return fresh.__name__
+
+
+def test_jit_spans_once_per_shared_tracer(tmp_path):
+    """Two stores sharing one tracer (as ShardedDB's shards do) record
+    each compile once; close() releases the listener."""
+    base = _jit_listeners()
+    tr = Tracer()
+    dbs = [LsmDB(str(tmp_path / f"db{i}"), obs_cfg(), tracer=tr)
+           for i in range(2)]
+    assert _jit_listeners() == base + 1
+    fun = _compile_fresh(11)
+    spans = [e for e in tr.to_chrome()["traceEvents"] if e["ph"] == "X"]
+    mine = [e for e in spans if e["args"]["fun"] in (fun, f"jit({fun})")]
+    by_name = {n: [e for e in mine if e["name"] == n]
+               for n in ("jit.trace", "jit.lower", "jit.compile")}
+    assert {n: len(v) for n, v in by_name.items()} == \
+        {"jit.trace": 1, "jit.lower": 1, "jit.compile": 1}
+    assert by_name["jit.compile"][0]["args"]["cache_hit"] is False
+    dbs[0].close()
+    assert _jit_listeners() == base + 1     # the other store still holds
+    dbs[1].close()
+    dbs[1].close()                          # a second close releases nothing
+    assert _jit_listeners() == base
+    n = len(tr)
+    _compile_fresh(12)
+    assert len(tr) == n                     # released: nothing recorded
+
+
+def test_null_tracer_registers_no_jit_listener(tmp_path):
+    from repro.obs import NULL_TRACER, watch_jit
+    base = _jit_listeners()
+    db = LsmDB(str(tmp_path / "db"), obs_cfg())
+    assert db.tracer is NULL_TRACER and _jit_listeners() == base
+    _compile_fresh(13)
+    db.close()
+    watch_jit(NULL_TRACER)()
+    assert _jit_listeners() == base
 
 
 def test_tracer_ring_buffer_bounded():
@@ -319,7 +422,16 @@ def _check_nesting(events):
             stack.append((t0, t1, name))
 
 
+def _inside(child, parents):
+    """``parents`` on ``child``'s thread that hold it."""
+    c0, c1 = child["ts"], child["ts"] + child["dur"]
+    return [p for p in parents if p["tid"] == child["tid"]
+            and p["ts"] <= c0 and c1 <= p["ts"] + p["dur"]]
+
+
 def test_span_nesting_async_device(tmp_path):
+    import jax
+    jax.clear_caches()      # the first launch traces and compiles here
     tr = Tracer()
     db = LsmDB(str(tmp_path / "db"),
                obs_cfg(engine="device", async_compaction=True), tracer=tr)
@@ -329,10 +441,29 @@ def test_span_nesting_async_device(tmp_path):
     db.wait_idle()
     db.close()
     events = tr.to_chrome()["traceEvents"]
-    names = {e["name"] for e in events if e["ph"] == "X"}
+    spans = [e for e in events if e["ph"] == "X"]
+    names = {e["name"] for e in spans}
     assert "db.put" in names and "flush.build" in names
-    assert "compact.execute" in names or "compact.batch_launch" in names
-    assert "compact.merge_phase2" in names   # modeled child phase
+    launches = [e for e in spans
+                if e["name"] in ("compact.execute", "compact.batch_launch")]
+    assert launches
+    # the launch's measured children, consecutive inside it
+    for launch in launches:
+        kids = sorted((e for e in spans if e["name"] in (
+            "compact.dispatch", "compact.device_wait", "compact.d2h")
+            and _inside(e, [launch])), key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == [
+            "compact.dispatch", "compact.device_wait", "compact.d2h"]
+        assert kids[-1]["args"]["bytes"] > 0
+    # JAX's own steps, inside the dispatch of a launch
+    dispatch = [e for e in spans if e["name"] == "compact.dispatch"]
+    jit = [e for e in spans if e["name"] in ("jit.trace", "jit.compile")
+           and _inside(e, dispatch)]
+    assert jit and all(e["args"]["fun"] for e in jit)
+    assert all(_inside(e, launches) for e in jit)
+    reads = [e for e in spans if e["name"] == "compact.read_inputs"]
+    assert reads and all(e["args"]["h2d_bytes"] > 0 for e in reads)
+    assert not [e for e in events if "modeled" in (e.get("args") or {})]
     _check_nesting(events)
 
 

@@ -94,3 +94,29 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compaction_program_names_for_v5e(one_chip, monkeypatch):
+    """The benchmark's device-trace readers match on these names: the
+    compaction program is ``jit_compact`` and its kernels are named
+    ``merge_runs`` and ``crc32_blocks_sections``."""
+    import re
+
+    from repro.core import compaction
+    from repro.core.formats import SSTImage
+    from repro.kernels import common
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    blocks = 16
+    img = SSTImage(*(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                     for shape, dtype in (
+                         u32(blocks, K, L), u32(blocks, K),
+                         u32(blocks, K, VW), ((blocks, K), jnp.int32),
+                         ((blocks,), jnp.int32), u32(blocks), u32(1, 1))))
+    text = compaction.compact.lower(
+        img, geom=GEOM, sort_mode="merge", backend="pallas",
+        run_lens=(blocks // 2 * K,) * 2).compile().as_text()
+    assert text.startswith("HloModule jit_compact,")
+    for kernel in ("merge_runs", "crc32_blocks_sections"):
+        assert re.search(rf"^\s*(ROOT )?%{kernel}(\.\d+)? = .*"
+                         r'custom_call_target="tpu_custom_call"', text,
+                         re.M), kernel
