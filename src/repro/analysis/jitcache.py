@@ -5,7 +5,7 @@ data-dependent shapes into a jitted entry point compiles once per
 distinct shape and fragments the cache (the silent 100x slowdown class).
 The repo's contract is pow2 bucketing before dispatch --
 ``scheduler.batch_signature`` / ``read._bucket`` / ``offload.next_pow2``
-/ ``pad_image_blocks`` -- so every call site of a jitted entry point
+/ ``offload.run_slots`` -- so every call site of a jitted entry point
 must show bucketing evidence in its enclosing function.
 
 Rule:
@@ -35,8 +35,8 @@ ENTRY_POINTS = {
 
 # any reference to one of these names counts as bucketing evidence
 BUCKET_HELPERS = {
-    "next_pow2", "round_up", "_bucket", "bucket", "pad_image_blocks",
-    "pad_blocks", "batch_signature", "bucket_blocks", "pad_to_bucket",
+    "next_pow2", "round_up", "_bucket", "bucket", "run_slots",
+    "slot_layout", "batch_signature", "bucket_blocks", "pad_to_bucket",
 }
 
 
@@ -109,7 +109,7 @@ class JitCacheChecker:
                     qualname=qualname, detail=callee,
                     message=f"'{callee}' is a jitted entry point but "
                             f"'{qualname}' shows no shape bucketing "
-                            "(next_pow2/_bucket/pad_image_blocks/...); "
+                            "(next_pow2/_bucket/run_slots/...); "
                             "data-dependent shapes fragment the jit "
                             "cache -- bucket, or baseline with a shape "
                             "argument"))

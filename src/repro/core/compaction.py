@@ -228,8 +228,8 @@ def compact(img: SSTImage, *, geom: SSTGeometry, bottom_level: bool = False,
 
     ``run_lens`` (static, entries per input SST; only consumed by
     ``sort_mode="merge"``) preserves the sorted-run structure of the
-    concatenation; it is part of the jit cache key, so callers should
-    bucket per-run sizes (see ``DeviceCompactionEngine``).  Merge mode
+    concatenation; it is part of the jit cache key, so callers lay runs
+    out in pow2 run slots (``offload.slot_layout``).  Merge mode
     *requires* it -- the input image is normally a concatenation of runs,
     and silently treating it as one sorted run would corrupt the output
     (use ``formats.concat_images(..., with_runs=True)``; a genuinely

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compaction, formats
+from repro.core import compaction
 from repro.core.formats import SSTGeometry, SSTImage
 
 
@@ -32,12 +32,13 @@ from repro.core.formats import SSTGeometry, SSTImage
 class CompactionExecutor:
     """Host handle for device-offloaded compactions.
 
-    ``sort_mode="merge"`` (the default) is run-aware: ``compact`` derives
-    the per-input run lengths from the image list and threads them through
-    the pipeline, so callers must pass one *sorted* image per input SST
-    (every SST written by this codebase is; see docs/compaction.md for the
-    contract).  ``debug_check_runs=True`` (or env ``REPRO_CHECK_RUNS=1``)
-    host-verifies that precondition on every job.
+    ``sort_mode="merge"`` (the default) is run-aware: ``compact`` lays
+    the input images out one per run slot (``slot_layout``) and threads
+    the slot lengths through the pipeline, so callers must pass one
+    *sorted* image per input SST (every SST written by this codebase is;
+    see docs/compaction.md for the contract).  ``debug_check_runs=True``
+    (or env ``REPRO_CHECK_RUNS=1``) host-verifies that precondition on
+    every job.
     """
     geom: SSTGeometry
     sort_mode: str = "merge"       # "merge" | "device" | "cooperative" | "xla"
@@ -47,23 +48,37 @@ class CompactionExecutor:
             "REPRO_CHECK_RUNS", "").strip().lower()
         in ("1", "true", "yes", "on"))
 
-    def compact(self, images: list[SSTImage], *, bottom_level: bool = False,
-                pad_blocks: int | None = None
+    def compact(self, images: list[SSTImage], *, bottom_level: bool = False
                 ) -> tuple[SSTImage, compaction.CompactionStats]:
-        """Compact the input set.  ``pad_blocks`` pads the concatenated
-        image up to a jit-stable block count; the padding becomes a
-        trailing all-sentinel run so the merge path stays exact."""
-        img, run_lens = formats.concat_images(images, with_runs=True)
-        if pad_blocks is not None:
-            img, run_lens = pad_image_blocks(img, pad_blocks, self.geom,
-                                             run_lens=run_lens)
+        """Compact one job's input set: stage it in its run slots and
+        launch it."""
+        img, run_lens, _ = self.stage(
+            [images], *run_slots([im.keys.shape[0] for im in images]))
+        return self.launch(img, run_lens, bottom_level=bottom_level)
+
+    def stage(self, jobs: list[list[SSTImage]], slots: int,
+              slot_blocks: int) -> tuple[SSTImage, tuple[int, ...], int]:
+        """Lay the jobs' runs out in run slots on the host
+        (``slot_layout``) and copy each field to the device once, stacked
+        on a leading job axis when there are several jobs, so no device
+        operation before the launch sees another shape than the launch's.
+        Returns ``(image, run_lens, bytes staged)``."""
+        host, run_lens = slot_layout(jobs, self.geom, slots, slot_blocks)
+        if len(jobs) == 1:
+            host = SSTImage(*(a[0] for a in host))
+        return (SSTImage(*(jnp.asarray(a) for a in host)), run_lens,
+                sum(a.nbytes for a in host))
+
+    def launch(self, img: SSTImage, run_lens: tuple[int, ...], *,
+               bottom_level: bool = False
+               ) -> tuple[SSTImage, compaction.CompactionStats]:
+        """One compaction over a staged slot image (``slot_layout``)."""
         if self.debug_check_runs and self.sort_mode == "merge":
             self._check_runs(img, run_lens)
-        out, stats = compaction.compact(
+        return compaction.compact(
             img, geom=self.geom, bottom_level=bottom_level,
             sort_mode=self.sort_mode, backend=self.backend,
             run_lens=run_lens if self.sort_mode == "merge" else None)
-        return out, stats
 
     def _check_runs(self, img: SSTImage, run_lens: tuple[int, ...]):
         """Debug path: assert every input run's phase-2 tuples are sorted
@@ -73,50 +88,21 @@ class CompactionExecutor:
         rows = compaction.build_tuples(up)
         merge_path.assert_runs_sorted(rows, run_lens)
 
-    def compact_many(self, jobs: list[list[SSTImage]], *,
-                     bottom_level: bool = False,
-                     pad_blocks: int | None = None
-                     ) -> list[tuple[SSTImage, compaction.CompactionStats]]:
-        """Compact several *same-shape* jobs in one stacked device launch.
-
-        Every job is one input image list; after per-job concatenation
-        (+ optional padding to ``pad_blocks``) all jobs must present
-        identical array shapes and -- in merge mode -- identical run
-        signatures, since ``run_lens`` is static for the whole batch
-        (callers group jobs by shape bucket first; see
-        ``DeviceCompactionEngine.compact_many``).  Returns per-job
-        ``(image, stats)`` in input order, bit-identical to calling
-        ``compact`` on each job alone: ``vmap`` runs the same integer
-        pipeline per batch lane."""
-        assert jobs, "compact_many needs at least one job"
-        imgs, sigs = [], []
-        for images in jobs:
-            img, run_lens = formats.concat_images(images, with_runs=True)
-            if pad_blocks is not None:
-                img, run_lens = pad_image_blocks(img, pad_blocks, self.geom,
-                                                 run_lens=run_lens)
-            if self.debug_check_runs and self.sort_mode == "merge":
-                self._check_runs(img, run_lens)
-            imgs.append(img)
-            sigs.append(tuple(run_lens))
-        if self.sort_mode == "merge" and any(s != sigs[0] for s in sigs):
-            raise ValueError(
-                f"compact_many jobs have mismatched run signatures {sigs}; "
-                "group jobs by shape bucket before batching")
-        if any(im.keys.shape != imgs[0].keys.shape for im in imgs):
-            raise ValueError(
-                "compact_many jobs have mismatched block counts "
-                f"{[im.keys.shape[0] for im in imgs]}; pass pad_blocks or "
-                "group jobs by shape bucket before batching")
-        stacked = SSTImage(*(jnp.stack(parts, axis=0)
-                             for parts in zip(*imgs)))
-        out, stats = compact_batch(
-            stacked, geom=self.geom, bottom_level=bottom_level,
+    def launch_many(self, img: SSTImage, run_lens: tuple[int, ...], *,
+                    bottom_level: bool = False
+                    ) -> tuple[SSTImage, compaction.CompactionStats]:
+        """Several jobs in one stacked device launch: ``img`` is
+        ``slot_layout``'s image of J jobs, every field ``[J, ...]``.
+        Returns the stacked output image and per-job stats, each lane
+        bit-identical to ``launch`` of that job alone: ``vmap`` runs the
+        same integer pipeline per batch lane."""
+        if self.debug_check_runs and self.sort_mode == "merge":
+            for j in range(img.keys.shape[0]):
+                self._check_runs(SSTImage(*(a[j] for a in img)), run_lens)
+        return compact_batch(
+            img, geom=self.geom, bottom_level=bottom_level,
             sort_mode=self.sort_mode, backend=self.backend,
-            run_lens=sigs[0] if self.sort_mode == "merge" else None)
-        return [(SSTImage(*(a[j] for a in out)),
-                 compaction.CompactionStats(*(s[j] for s in stats)))
-                for j in range(len(jobs))]
+            run_lens=run_lens if self.sort_mode == "merge" else None)
 
     def compact_overlapped(self, images: list[SSTImage], *,
                            bottom_level: bool = False):
@@ -153,7 +139,8 @@ def compact_batch(img: SSTImage, *, geom: SSTGeometry,
     field is ``[J, ...]`` of one job's shape).  Compaction procedures are
     data-independent (the paper's core scaling argument), so the whole
     batch is a single ``vmap`` over the job axis: one dispatch, one jit
-    cache entry per (shape bucket, run signature), J jobs of occupancy.
+    cache entry per (job count, run-slot layout, bottom level), J jobs of
+    occupancy.
     Returns the stacked output image plus per-job ``CompactionStats``
     (``crc_ok`` stays a per-job verdict -- one corrupt input must not
     taint its batch mates)."""
@@ -190,44 +177,55 @@ def next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
-def pad_image_blocks(img: SSTImage, n_blocks: int, geom: SSTGeometry,
-                     run_lens: tuple[int, ...] | None = None):
-    """Append empty (nvalid=0) blocks so the block count hits a jit-stable
-    bucket.  Padding blocks carry the correct CRC of an all-zero wire block
-    so phase-1 verification still passes.
+def run_slots(block_counts) -> tuple[int, int]:
+    """``(slots, slot_blocks)`` of a job's run-slot layout: a power-of-two
+    number of slots, one per input run, each a power-of-two number of
+    blocks that holds the largest run.  The jit signature of a launch
+    depends on this pair (and the bottom level) alone."""
+    return next_pow2(len(block_counts)), next_pow2(max(block_counts))
 
-    When ``run_lens`` (per-input entry counts) is given, returns
-    ``(padded_img, run_lens + (pad_entries,))``: the padding is appended as
-    one trailing sentinel run, keeping the merge path's sorted-run
-    precondition intact (padding tuples get the all-ones key and ascending
-    index, which is sorted by construction)."""
+
+def slot_layout(jobs: list[list[SSTImage]], geom: SSTGeometry, slots: int,
+                slot_blocks: int) -> tuple[SSTImage, tuple[int, ...]]:
+    """Lay out the input runs of one or more jobs on the host.
+
+    Returns a numpy image whose fields are ``[J, slots * slot_blocks,
+    ...]`` (J = ``len(jobs)``) and the launch's ``run_lens``
+    (``(slot_blocks * block_kvs,) * slots``).  Run ``i`` of job ``j``
+    fills the front of slot ``i`` in input order; the rest of each slot,
+    and every slot past the job's runs, are empty blocks (``nvalid`` 0)
+    carrying the CRC of an all-zero wire block, so phase-1 verification
+    passes.  Their rows get the all-ones sentinel key in
+    ``build_tuples`` and sort last inside their slot, so every slot is a
+    sorted run and the merge stays exact.  The input filters are not
+    read by the pipeline: ``bloom`` is a ``[J, 1, 1]`` placeholder."""
     import numpy as np
 
     from repro.kernels import tables
-    b = img.keys.shape[0]
-    extra = n_blocks - b
-    if extra <= 0:
-        return img if run_lens is None else (img, run_lens)
-    zero_crc = np.uint32(
-        tables.crc32_zero_message(geom.wire_words_per_block * 4))
-    pad = lambda a, shape: jnp.concatenate(  # noqa: E731
-        [jnp.asarray(a), jnp.zeros(shape, jnp.asarray(a).dtype)], axis=0)
+    total = slots * slot_blocks
     k, lanes, vw = geom.block_kvs, geom.key_lanes, geom.value_words
-    bloom = img.bloom
-    if bloom.shape[0] == b:  # block-granularity filters track blocks
-        bloom = pad(bloom, (extra, bloom.shape[1]))
-    padded = SSTImage(
-        keys=pad(img.keys, (extra, k, lanes)),
-        meta=pad(img.meta, (extra, k)),
-        vals=pad(img.vals, (extra, k, vw)),
-        shared=pad(img.shared, (extra, k)),
-        nvalid=pad(img.nvalid, (extra,)),
-        crc=jnp.concatenate([jnp.asarray(img.crc),
-                             jnp.full((extra,), zero_crc, jnp.uint32)]),
-        bloom=bloom)
-    if run_lens is None:
-        return padded
-    return padded, tuple(run_lens) + (extra * k,)
+    n = len(jobs)
+    zero_crc = tables.crc32_zero_message(geom.wire_words_per_block * 4)
+    img = SSTImage(
+        keys=np.zeros((n, total, k, lanes), np.uint32),
+        meta=np.zeros((n, total, k), np.uint32),
+        vals=np.zeros((n, total, k, vw), np.uint32),
+        shared=np.zeros((n, total, k), np.int32),
+        nvalid=np.zeros((n, total), np.int32),
+        crc=np.full((n, total), zero_crc, np.uint32),
+        bloom=np.zeros((n, 1, 1), np.uint32))
+    for j, images in enumerate(jobs):
+        if len(images) > slots:
+            raise ValueError(f"{len(images)} runs do not fit {slots} slots")
+        for i, im in enumerate(images):
+            b = im.keys.shape[0]
+            if b > slot_blocks:
+                raise ValueError(f"a run of {b} blocks does not fit a "
+                                 f"slot of {slot_blocks}")
+            at = i * slot_blocks
+            for dst, src in zip(img[:-1], im[:-1]):
+                dst[j, at:at + b] = np.asarray(src)
+    return img, (slot_blocks * k,) * slots
 
 
 def sharded_compact(img: SSTImage, mesh: Mesh, axes, *, geom: SSTGeometry,

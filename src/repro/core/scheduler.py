@@ -38,26 +38,19 @@ class CompactionJob:
         return sum(f.size_bytes for f in self.all_inputs)
 
 
-def batch_signature(block_counts, bottom_level: bool,
-                    sort_mode: str = "merge") -> tuple:
-    """Shape-bucket key for batched device launches.
+def batch_signature(block_counts, bottom_level: bool) -> tuple:
+    """The jit signature of a device launch: ``(slots, slot_blocks,
+    bottom_level)``.
 
-    Jobs whose signatures are equal present identical array shapes (and,
-    in merge mode, identical static run signatures) after the engine's
-    pow2 padding, so they can stack into one vmapped launch
-    (``DeviceCompactionEngine.compact_many``).  ``block_counts`` are the
-    per-input SST block counts of one job.
-
-    * merge mode: each input run is padded to a pow2 block count and the
-      total to a pow2 bucket, so the key is (per-run padded counts, bucket,
-      bottom_level);
-    * re-sort modes ignore run structure: only the padded total matters.
-    """
-    from repro.core.offload import next_pow2
-    if sort_mode == "merge":
-        padded = tuple(next_pow2(b) for b in block_counts)
-        return (padded, next_pow2(sum(padded)), bool(bottom_level))
-    return ((), next_pow2(sum(block_counts)), bool(bottom_level))
+    ``block_counts`` are the per-input SST block counts of one job.  The
+    engine lays the job's runs out one per slot (``offload.run_slots``:
+    a power-of-two slot count, each slot a power-of-two block count that
+    holds the largest run), so jobs with equal signatures present
+    identical array shapes and identical static ``run_lens``: they reuse
+    one compiled program, and several of them stack into one vmapped
+    launch (``DeviceCompactionEngine.compact_many``)."""
+    from repro.core.offload import run_slots
+    return (*run_slots(block_counts), bool(bottom_level))
 
 
 @dataclasses.dataclass

@@ -360,11 +360,11 @@ class DeviceCompactionEngine:
         self.executor = CompactionExecutor(geom, sort_mode=sort_mode,
                                            backend=backend)
         self._reader = None
-        # jit cache bookkeeping: every job is padded to a power-of-two
-        # block count, so repeated jobs of similar size reuse the trace
-        # instead of recompiling.  The key is the launch's jit signature
-        # (bucket, per-run padded block counts, bottom level, and the job
-        # count of a stacked launch); a miss = first launch of a signature.
+        # jit cache bookkeeping: every job is laid out in run slots
+        # (``offload.run_slots``), so jobs of one slot class reuse the
+        # compiled program.  The key is the launch's jit signature (slots,
+        # slot blocks, bottom level, and the job count of a stacked
+        # launch); a miss = first launch of a signature.
         self.jit_signature_counts: dict[tuple, int] = {}
         self.jit_bucket_hits = 0
         self.jit_bucket_misses = 0
@@ -389,13 +389,16 @@ class DeviceCompactionEngine:
             self._reader.close()
             self._reader = None
 
-    def _note_signature(self, sig: tuple):
+    def _note_signature(self, sig: tuple) -> bool:
+        """Count a launch under its jit signature; True if it was seen
+        before (the launch reuses a compiled program)."""
         seen = self.jit_signature_counts.get(sig, 0)
         self.jit_signature_counts[sig] = seen + 1
         if seen:
             self.jit_bucket_hits += 1
         else:
             self.jit_bucket_misses += 1
+        return bool(seen)
 
     def _cpu_engine(self) -> CpuCompactionEngine:
         """The lazily-built degraded-mode twin (bit-identical output)."""
@@ -443,13 +446,13 @@ class DeviceCompactionEngine:
 
     def compact(self, images, *, bottom_level: bool = False):
         def attempt():
-            import jax.numpy as jnp
-            t0 = time.perf_counter()  # H2D staging counts as host work
-            imgs = [SSTImage(*(jnp.asarray(np.asarray(a)) for a in im))
-                    for im in images]
-            real_blocks = sum(np.asarray(im.keys).shape[0] for im in images)
-            return self._compact_staged(imgs, real_blocks,
-                                        bottom_level=bottom_level, t0=t0)
+            from repro.core.scheduler import batch_signature
+            t0 = time.perf_counter()  # layout and H2D staging are host work
+            blocks = [im.keys.shape[0] for im in images]
+            sig = batch_signature(blocks, bottom_level)
+            staged = self.executor.stage([images], *sig[:2])
+            return self._compact_staged(staged, sig, real_blocks=sum(blocks),
+                                        t0=t0)
 
         return self._with_fallback(
             attempt,
@@ -457,29 +460,25 @@ class DeviceCompactionEngine:
                                                bottom_level=bottom_level))
 
     def compact_paths(self, paths: list[str], *, bottom_level: bool = False):
-        """Compact straight from SST files, double-buffering host reads:
-        while image *i* is staged host->device, a dedicated I/O thread is
-        already reading file *i+1* -- and because JAX dispatch is async,
-        the first reads of this job overlap the device tail of the
-        previous one (the paper's cross-job "judicious data movement")."""
+        """Compact straight from SST files: a dedicated I/O thread reads
+        ahead of the consumer, and the job's runs are then laid out in
+        their run slots on the host and staged to the device once."""
         def attempt():
-            import jax.numpy as jnp
-
             from repro.core.background import PrefetchReader
+            from repro.core.scheduler import batch_signature
             from repro.lsm import sstable
             t0 = time.perf_counter()
             if self._reader is None:
                 self._reader = PrefetchReader()
             with self.tracer.span("compact.read_inputs",
                                   files=len(paths)) as sp:
-                imgs, real_blocks, staged = [], 0, 0
-                for im in self._reader.read_all(paths, sstable.read_sst):
-                    real_blocks += im.keys.shape[0]
-                    staged += sum(a.nbytes for a in im)
-                    imgs.append(SSTImage(*(jnp.asarray(a) for a in im)))
-                sp.set(h2d_bytes=staged)
-            return self._compact_staged(imgs, real_blocks,
-                                        bottom_level=bottom_level, t0=t0)
+                imgs = list(self._reader.read_all(paths, sstable.read_sst))
+                blocks = [im.keys.shape[0] for im in imgs]
+                sig = batch_signature(blocks, bottom_level)
+                staged = self.executor.stage([imgs], *sig[:2])
+                sp.set(h2d_bytes=staged[2])
+            return self._compact_staged(staged, sig, real_blocks=sum(blocks),
+                                        t0=t0)
 
         return self._with_fallback(
             attempt,
@@ -488,19 +487,19 @@ class DeviceCompactionEngine:
 
     def compact_many(self, jobs: list[tuple[list[str], bool]]
                      ) -> list[tuple[SSTImage, EngineStats]]:
-        """Compact several independent jobs, coalescing same-shape-bucket
-        jobs into single stacked device launches.
+        """Compact several independent jobs, coalescing jobs of one jit
+        signature into single stacked device launches.
 
         ``jobs``: ``[(input_paths, bottom_level)]`` -- typically one job
         per shard, published by ``ShardedDB``'s global queue.  Jobs are
-        grouped by ``scheduler.batch_signature`` of their *actual* input
-        block counts; each >=2-job group becomes ONE vmapped dispatch
-        (``offload.compact_batch``) with per-job CRC verdicts, singleton
-        groups take the ordinary single-job path.  Results come back in
-        input order and are bit-identical to per-job ``compact_paths``.
+        grouped by ``scheduler.batch_signature`` (run-slot layout and
+        bottom level) of their *actual* input block counts; each group is
+        laid out and staged as one image.  A >=2-job group becomes ONE
+        vmapped dispatch (``offload.compact_batch``) with per-job CRC
+        verdicts; singleton groups take the ordinary single-job path.
+        Results come back in input order and are bit-identical to per-job
+        ``compact_paths``.
         """
-        import jax.numpy as jnp
-
         from repro.core.background import PrefetchReader
         from repro.core.scheduler import batch_signature
         from repro.lsm import sstable
@@ -509,37 +508,33 @@ class DeviceCompactionEngine:
         if self._reader is None:
             self._reader = PrefetchReader()
         flat_paths = [p for paths, _ in jobs for p in paths]
+        job_imgs, groups, off = [], {}, 0
         with self.tracer.span("compact.read_inputs",
                               files=len(flat_paths)) as sp:
             flat_imgs = list(self._reader.read_all(flat_paths,
                                                    sstable.read_sst))
-            # every image read here is staged to the device next
-            sp.set(h2d_bytes=sum(a.nbytes for im in flat_imgs for a in im))
-        t_read = time.perf_counter() - t_read0
-        job_imgs, job_blocks, off = [], [], 0
-        for paths, _ in jobs:
-            imgs = flat_imgs[off:off + len(paths)]
-            off += len(paths)
-            job_imgs.append(imgs)
-            job_blocks.append([im.keys.shape[0] for im in imgs])
-
-        groups: dict[tuple, list[int]] = {}
-        for j, (_, bottom) in enumerate(jobs):
-            sig = batch_signature(job_blocks[j], bottom,
-                                  sort_mode=self.executor.sort_mode)
-            groups.setdefault(sig, []).append(j)
-
+            for j, (paths, bottom) in enumerate(jobs):
+                imgs = flat_imgs[off:off + len(paths)]
+                off += len(paths)
+                job_imgs.append(imgs)
+                sig = batch_signature([im.keys.shape[0] for im in imgs],
+                                      bottom)
+                groups.setdefault(sig, []).append(j)
+            staged = {sig: self.executor.stage([job_imgs[j] for j in idxs],
+                                               *sig[:2])
+                      for sig, idxs in groups.items()}
+            sp.set(h2d_bytes=sum(st[2] for st in staged.values()))
+        read_share = (time.perf_counter() - t_read0) / max(1, len(jobs))
         results: list = [None] * len(jobs)
-        read_share = t_read / max(1, len(jobs))
 
-        def single(j):
+        def single(j, sig, st=None):
             """One prefetched job through the device path (+ fallback)."""
             def attempt():
                 t0 = time.perf_counter()
-                imgs = [SSTImage(*(jnp.asarray(a) for a in im))
-                        for im in job_imgs[j]]
+                staged = st or self.executor.stage([job_imgs[j]], *sig[:2])
                 out, es = self._compact_staged(
-                    imgs, sum(job_blocks[j]), bottom_level=jobs[j][1],
+                    staged, sig,
+                    real_blocks=sum(im.keys.shape[0] for im in job_imgs[j]),
                     t0=t0)
                 es.host_seconds += read_share
                 return out, es
@@ -550,13 +545,14 @@ class DeviceCompactionEngine:
                     job_imgs[j], bottom_level=jobs[j][1]))
 
         for sig, idxs in groups.items():
+            st = staged.pop(sig)
             if len(idxs) == 1:
-                results[idxs[0]] = single(idxs[0])
+                results[idxs[0]] = single(idxs[0], sig, st)
                 continue
             try:
                 results_group = self._compact_batched(
-                    [job_imgs[j] for j in idxs], bucket=sig[1],
-                    bottom_level=jobs[idxs[0]][1], read_share=read_share)
+                    st, sig, [job_imgs[j] for j in idxs],
+                    read_share=read_share)
             except faults.FaultInjected as e:
                 # the stacked launch faulted: isolate by re-running the
                 # group's jobs one by one (device retry + CPU fallback
@@ -566,14 +562,14 @@ class DeviceCompactionEngine:
                 results_group = None
             if results_group is None:
                 for j in idxs:
-                    results[j] = single(j)
+                    results[j] = single(j, sig)
             else:
                 for j, res in zip(idxs, results_group):
                     if not res[1].crc_ok:
                         # per-job negative verdict inside a batch: get an
                         # authoritative single-job verdict (still fails
                         # for genuinely corrupt inputs -- on the CPU)
-                        res = single(j)
+                        res = single(j, sig)
                     results[j] = res
         if self.tracer.enabled:
             self.tracer.complete(
@@ -582,52 +578,46 @@ class DeviceCompactionEngine:
                 args={"jobs": len(jobs), "groups": len(groups)})
         return results
 
-    def _compact_batched(self, group_imgs, *, bucket, bottom_level,
-                         read_share):
-        """One stacked launch over >=2 same-signature jobs."""
-        import jax.numpy as jnp
+    @staticmethod
+    def _launch_args(jobs: int, sig: tuple, hit: bool) -> dict:
+        slots, slot_blocks, _ = sig
+        return {"jobs": jobs, "bucket": slots * slot_blocks, "slots": slots,
+                "slot_blocks": slot_blocks, "sig_hit": hit}
 
-        from repro.core import offload
-        from repro.core.scheduler import batch_signature
+    def _sort_model(self, sig: tuple) -> float:
+        slots, slot_blocks, _ = sig
+        return model_sort_seconds(
+            slots * slot_blocks * self.geom.block_kvs,
+            self.geom.key_lanes + 2, slots, self.executor.sort_mode)
+
+    def _compact_batched(self, staged, sig, group_imgs, *, read_share):
+        """One stacked launch over >=2 jobs of one signature."""
         t0 = time.perf_counter()
-        staged = []
-        for imgs in group_imgs:
-            imgs = [SSTImage(*(jnp.asarray(np.asarray(a)) for a in im))
-                    for im in imgs]
-            if self.executor.sort_mode == "merge":
-                imgs = [offload.pad_image_blocks(
-                    im, offload.next_pow2(im.keys.shape[0]), self.geom)
-                    for im in imgs]
-            staged.append(imgs)
-        n_jobs = len(staged)
-        self._note_signature(("batch", n_jobs, batch_signature(
-            [im.keys.shape[0] for im in staged[0]], bottom_level,
-            sort_mode=self.executor.sort_mode)))
+        img, run_lens, _ = staged
+        bottom_level = sig[2]
+        n_jobs = len(group_imgs)
+        hit = self._note_signature(("batch", n_jobs) + sig)
         self.batch_launches += 1
         self.batch_jobs += n_jobs
         self.max_batch_jobs = max(self.max_batch_jobs, n_jobs)
-        outs, exec_wall = self._execute(
-            "compact.batch_launch", {"jobs": n_jobs, "bucket": bucket},
-            lambda: self.executor.compact_many(
-                staged, bottom_level=bottom_level, pad_blocks=bucket))
+        (out, st), exec_wall = self._execute(
+            "compact.batch_launch", self._launch_args(n_jobs, sig, hit),
+            lambda: self.executor.launch_many(img, run_lens,
+                                              bottom_level=bottom_level))
         host_share = max(time.perf_counter() - t0 - exec_wall, 0.0) / n_jobs
         wire = self.geom.wire_words_per_block * 4
         results = []
-        for (out, s), imgs, raw in zip(outs, staged, group_imgs):
-            total_blocks = sum(im.keys.shape[0] for im in imgs)
+        for j, raw in enumerate(group_imgs):
             stats = EngineStats(
-                n_input=int(s.n_input), n_live=int(s.n_live),
-                n_dropped=int(s.n_dropped), crc_ok=bool(s.crc_ok),
+                n_input=int(st.n_input[j]), n_live=int(st.n_live[j]),
+                n_dropped=int(st.n_dropped[j]), crc_ok=bool(st.crc_ok[j]),
                 bytes_in=sum(im.keys.shape[0] for im in raw) * wire,
-                bytes_out=int(s.bytes_out), batched=True)
+                bytes_out=int(st.bytes_out[j]), batched=True)
             stats.host_seconds = host_share + read_share
             stats.device_seconds = model_device_seconds(
                 stats.bytes_in, stats.bytes_out, self.geom)
-            n_runs = len(imgs) + (1 if bucket > total_blocks else 0)
-            stats.sort_seconds = model_sort_seconds(
-                bucket * self.geom.block_kvs, self.geom.key_lanes + 2,
-                n_runs, self.executor.sort_mode)
-            results.append((out, stats))
+            stats.sort_seconds = self._sort_model(sig)
+            results.append((SSTImage(*(a[j] for a in out)), stats))
         return results
 
     def _execute(self, span: str, args: dict, launch):
@@ -635,9 +625,10 @@ class DeviceCompactionEngine:
         consecutive children: ``compact.dispatch`` (the call into the
         jitted pipeline: trace, lower, compile or persistent-cache load,
         enqueue), ``compact.device_wait`` (until the outputs are ready)
-        and ``compact.d2h`` (the output images copied to the host).
-        ``launch()`` returns ``[(image, stats)]``; returns them with host
-        images, and the launch's wall seconds."""
+        and ``compact.d2h`` (the outputs copied to the host).
+        ``launch()`` returns ``(image, stats)``, stacked on a job axis for
+        a batch; returns them as host arrays, and the launch's wall
+        seconds."""
         import jax
         t0 = time.perf_counter_ns()
         faults.fire("engine.launch")
@@ -647,44 +638,27 @@ class DeviceCompactionEngine:
         faults.fire("engine.crc")
         jax.block_until_ready(res)
         t3 = time.perf_counter_ns()
-        outs = [(SSTImage(*(np.asarray(a) for a in out)), s)
-                for out, s in res]
+        out, stats = jax.tree.map(np.asarray, res)
         t4 = time.perf_counter_ns()
         tr = self.tracer
         if tr.enabled:
             tr.complete(span, t0, t4 - t0, args=args)
             tr.complete("compact.dispatch", t1, t2 - t1)
             tr.complete("compact.device_wait", t2, t3 - t2)
-            tr.complete("compact.d2h", t3, t4 - t3, args={
-                "bytes": sum(a.nbytes for out, _ in outs for a in out)})
-        return outs, (t4 - t0) / 1e9
+            tr.complete("compact.d2h", t3, t4 - t3,
+                        args={"bytes": sum(a.nbytes for a in out)})
+        return (out, stats), (t4 - t0) / 1e9
 
-    def _compact_staged(self, imgs, real_blocks, *, bottom_level, t0):
-        from repro.core import offload
-        from repro.core.scheduler import batch_signature
-        if self.executor.sort_mode == "merge":
-            # run-aligned bucketing: the per-run entry counts are part of
-            # the merge pipeline's jit cache key, so pad every input run
-            # up to a pow2 block count (padding rows carry the sentinel
-            # key and sort last inside their run) -- repeated jobs with
-            # similar input sizes then reuse the trace
-            imgs = [offload.pad_image_blocks(
-                im, offload.next_pow2(im.keys.shape[0]), self.geom)
-                for im in imgs]
-        # bucket the total block count to a power of two: stable jit shapes
-        # across jobs (padding blocks are empty and carry the zero-block
-        # CRC; the executor appends them as a trailing sentinel run)
-        total_blocks = sum(im.keys.shape[0] for im in imgs)
-        bucket = offload.next_pow2(total_blocks)
-        self._note_signature(("one", batch_signature(
-            [im.keys.shape[0] for im in imgs], bottom_level,
-            sort_mode=self.executor.sort_mode)))
+    def _compact_staged(self, staged, sig, *, real_blocks, t0):
+        img, run_lens, _ = staged
+        bottom_level = sig[2]
+        hit = self._note_signature(("one",) + sig)
         # the launch is device work, not host coordination: its wall time
         # is kept out of host_seconds
-        [(out, s)], exec_wall = self._execute(
-            "compact.execute", {"jobs": 1, "bucket": bucket},
-            lambda: [self.executor.compact(imgs, bottom_level=bottom_level,
-                                           pad_blocks=bucket)])
+        (out, s), exec_wall = self._execute(
+            "compact.execute", self._launch_args(1, sig, hit),
+            lambda: self.executor.launch(img, run_lens,
+                                         bottom_level=bottom_level))
         wire = self.geom.wire_words_per_block * 4
         stats = EngineStats(
             n_input=int(s.n_input), n_live=int(s.n_live),
@@ -693,12 +667,7 @@ class DeviceCompactionEngine:
         stats.host_seconds = max(time.perf_counter() - t0 - exec_wall, 0.0)
         stats.device_seconds = model_device_seconds(
             stats.bytes_in, stats.bytes_out, self.geom)
-        # the trailing padding run only exists when the bucket pad is
-        # non-empty
-        n_runs = len(imgs) + (1 if bucket > total_blocks else 0)
-        stats.sort_seconds = model_sort_seconds(
-            bucket * self.geom.block_kvs, self.geom.key_lanes + 2,
-            n_runs, self.executor.sort_mode)
+        stats.sort_seconds = self._sort_model(sig)
         return out, stats
 
     def build_image(self, keys, meta, vals, n_blocks=None) -> SSTImage:

@@ -2,11 +2,11 @@
 entry point defined in-module, and a ``self.*`` method receiver.  Must
 produce zero findings."""
 from repro.core import ops
-from repro.core.offload import next_pow2, pad_image_blocks
+from repro.core.offload import run_slots, slot_layout
 
 
 def compact_all(runs):
-    runs = [pad_image_blocks(r, next_pow2(len(r))) for r in runs]
+    runs = slot_layout([runs], None, *run_slots([len(r) for r in runs]))
     merged = ops.merge_runs(runs)
     return ops.sort_tuples(merged)
 

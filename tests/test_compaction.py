@@ -201,20 +201,116 @@ def test_merge_mode_agrees_with_all_modes():
 
 
 def test_executor_merge_with_padding_run():
-    """The executor carries run lengths through concat + bucket padding
-    (trailing sentinel run) and matches an xla-mode executor exactly."""
+    """The executor lays the runs out one per run slot (three runs take
+    four slots: the last is an all-sentinel padding run) and matches an
+    xla-mode executor exactly."""
     rng = np.random.default_rng(9)
     images = _random_run_images(rng, (30, 12, 45))
     ex_m = offload.CompactionExecutor(GEOM, sort_mode="merge",
                                       debug_check_runs=True)
     ex_x = offload.CompactionExecutor(GEOM, sort_mode="xla")
-    total = sum(im.keys.shape[0] for im in images)
-    pad_to = offload.next_pow2(total + 3)
-    out_m, _ = ex_m.compact(images, pad_blocks=pad_to)
-    out_x, _ = ex_x.compact(images, pad_blocks=pad_to)
+    out_m, _ = ex_m.compact(images)
+    out_x, _ = ex_x.compact(images)
+    slots, slot_blocks = offload.run_slots(
+        [im.keys.shape[0] for im in images])
+    assert (slots, slot_blocks) == (4, 4)
+    assert out_m.keys.shape[0] == slots * slot_blocks
     for field, a, b in zip(out_m._fields, out_m, out_x):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f"field {field}")
+
+
+def test_slot_layout_places_each_run_in_its_slot():
+    """Run i fills the front of slot i; the rest of the slot and every
+    slot past the runs are empty blocks with the zero-block CRC."""
+    from repro.kernels import tables
+    rng = np.random.default_rng(10)
+    images = _random_run_images(rng, (40, 20, 64))    # 3, 2, 4 blocks
+    host, run_lens = offload.slot_layout([images], GEOM, 4, 4)
+    assert host.keys.shape == (1, 16, GEOM.block_kvs, GEOM.key_lanes)
+    assert run_lens == (4 * GEOM.block_kvs,) * 4
+    zero_crc = tables.crc32_zero_message(GEOM.wire_words_per_block * 4)
+    for i, im in enumerate(images):
+        b = im.keys.shape[0]
+        for field, got, want in zip(im._fields[:-1], host[:-1], im[:-1]):
+            np.testing.assert_array_equal(
+                got[0, 4 * i:4 * i + b], np.asarray(want), err_msg=field)
+        pad = slice(4 * i + b, 4 * (i + 1))
+        assert not host.nvalid[0, pad].any()
+        assert (host.crc[0, pad] == zero_crc).all()
+    assert not host.nvalid[0, 12:].any()
+    assert (host.crc[0, 12:] == zero_crc).all()
+    with pytest.raises(ValueError, match="slot"):
+        offload.slot_layout([images], GEOM, 2, 4)
+    with pytest.raises(ValueError, match="slot"):
+        offload.slot_layout([images], GEOM, 4, 2)
+
+
+def _device_engine():
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+    return DeviceCompactionEngine(GEOM)
+
+
+def test_jobs_of_one_slot_class_reuse_one_program():
+    """A job with more runs of other lengths, in the same class (at most
+    8 runs of at most 4 blocks), has the same signature, counts as a hit
+    and adds no jit cache entry: the 14- and 15-run L1->L2 jobs of a
+    store at the paper's geometry, scaled down."""
+    from repro.core.scheduler import batch_signature
+    rng = np.random.default_rng(11)
+    first = _random_run_images(rng, (64, 50, 64, 33, 64))
+    second = _random_run_images(rng, (64,) * 7)
+    sigs = [batch_signature([im.keys.shape[0] for im in job], True)
+            for job in (first, second)]
+    assert sigs[0] == sigs[1] == (8, 4, True)
+    eng = _device_engine()
+    eng.compact(first, bottom_level=True)
+    entries = compaction.compact._cache_size()
+    eng.compact(second, bottom_level=True)
+    assert compaction.compact._cache_size() == entries
+    assert (eng.jit_bucket_misses, eng.jit_bucket_hits) == (1, 1)
+    assert eng.jit_signature_counts == {("one", 8, 4, True): 2}
+
+
+@pytest.mark.parametrize("sizes", [(32, 32, 32, 32, 32), (32, 32, 40)],
+                         ids=["slots_4_to_8", "slot_blocks_2_to_4"])
+def test_job_crossing_a_slot_class_gets_a_new_signature(sizes):
+    rng = np.random.default_rng(12)
+    base = _random_run_images(rng, (32, 20, 32))
+    other = _random_run_images(rng, sizes)
+    eng = _device_engine()
+    eng.compact(base)
+    eng.compact(other)
+    assert (eng.jit_bucket_misses, eng.jit_bucket_hits) == (2, 0)
+    assert ("one", 4, 2, False) in eng.jit_signature_counts
+    assert len(eng.jit_signature_counts) == 2
+
+
+@pytest.mark.parametrize("sizes,bottom", [
+    ((53, 128, 128, 100), False),       # runs of 4, 8, 8, 7 blocks
+    ((40, 70, 20, 90, 30), False),      # 5 runs: three empty slots
+    ((77,), False),                     # one run, one slot
+    ((60, 45, 80), True),               # bottom level: tombstones go
+], ids=["mixed_runs", "empty_trailing_slots", "single_run",
+        "bottom_tombstones"])
+def test_slot_layout_bit_identical_to_cpu_engine(sizes, bottom):
+    from repro.lsm import sstable
+    from repro.lsm.cpu_engine import CpuCompactionEngine
+    rng = np.random.default_rng(len(sizes) + 20 * bottom)
+    images = _random_run_images(rng, sizes)
+    out_d, st_d = _device_engine().compact(images, bottom_level=bottom)
+    out_c, st_c = CpuCompactionEngine(GEOM).compact(images,
+                                                    bottom_level=bottom)
+    for field, a, b in zip(out_d._fields, sstable.trim_image(out_d),
+                           sstable.trim_image(out_c)):
+        np.testing.assert_array_equal(a, b, err_msg=f"field {field}")
+    assert (st_d.n_input, st_d.n_live, st_d.n_dropped, st_d.crc_ok,
+            st_d.bytes_in, st_d.bytes_out) == \
+        (st_c.n_input, st_c.n_live, st_c.n_dropped, st_c.crc_ok,
+         st_c.bytes_in, st_c.bytes_out)
+    if bottom:
+        assert st_d.n_dropped > 0
+        assert all(is_value for _, _, is_value, _ in read_entries(out_d))
 
 
 def test_merge_mode_requires_run_lens():

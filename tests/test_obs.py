@@ -425,8 +425,10 @@ def _check_nesting(events):
 def _inside(child, parents):
     """``parents`` on ``child``'s thread that hold it."""
     c0, c1 = child["ts"], child["ts"] + child["dur"]
+    # the same 1e-6 us epsilon as _check_nesting: a child that ends with
+    # its parent can sum to one float ulp past it
     return [p for p in parents if p["tid"] == child["tid"]
-            and p["ts"] <= c0 and c1 <= p["ts"] + p["dur"]]
+            and p["ts"] <= c0 and c1 <= p["ts"] + p["dur"] + 1e-6]
 
 
 def test_span_nesting_async_device(tmp_path):
@@ -435,6 +437,14 @@ def test_span_nesting_async_device(tmp_path):
     tr = Tracer()
     db = LsmDB(str(tmp_path / "db"),
                obs_cfg(engine="device", async_compaction=True), tracer=tr)
+    # what reaches each launch: the staged image and its run lengths
+    executor, staged = db.engine.executor, []
+    launch = executor.launch
+
+    def recording_launch(img, run_lens, **kw):
+        staged.append((img.keys, run_lens))
+        return launch(img, run_lens, **kw)
+    executor.launch = recording_launch
     rng = np.random.default_rng(5)
     for i in range(600):
         db.put(b"key%03d" % rng.integers(0, 120), b"v%06d" % i)
@@ -447,6 +457,18 @@ def test_span_nesting_async_device(tmp_path):
     launches = [e for e in spans
                 if e["name"] in ("compact.execute", "compact.batch_launch")]
     assert launches
+    # each launch gets an image of exactly slots * slot_blocks blocks,
+    # already on the device, and says whether its signature was seen
+    execs = sorted((e for e in launches if e["name"] == "compact.execute"),
+                   key=lambda e: e["ts"])
+    assert len(execs) == len(staged)
+    for e, (keys, run_lens) in zip(execs, staged):
+        a = e["args"]
+        assert isinstance(keys, jax.Array)
+        assert keys.shape[0] == a["slots"] * a["slot_blocks"] == a["bucket"]
+        assert run_lens == (a["slot_blocks"] * GEOM.block_kvs,) * a["slots"]
+        assert isinstance(a["sig_hit"], bool)
+    assert execs[0]["args"]["sig_hit"] is False
     # the launch's measured children, consecutive inside it
     for launch in launches:
         kids = sorted((e for e in spans if e["name"] in (

@@ -128,16 +128,11 @@ def test_sharded_matches_single_db_oracle(tmp_path, shards):
 # ---------------------------------------------------------------------------
 
 
-def test_compact_many_bit_identical_and_batched(tmp_path):
-    """compact_many must (a) coalesce >=2 same-bucket jobs into one
-    stacked launch and (b) emit output bit-identical to sequential
-    per-job compact_paths."""
+def _sst_writer(eng, tmp_path, rng):
+    """``make_sst(prefix, n)``: write an SST of ``n`` sorted keys under
+    ``prefix`` and return its path."""
     from repro.core import formats
     from repro.lsm import sstable
-    from repro.lsm.cpu_engine import DeviceCompactionEngine
-
-    eng = DeviceCompactionEngine(GEOM)
-    rng = np.random.default_rng(3)
     no = [0]
 
     def make_sst(prefix, n):
@@ -154,6 +149,34 @@ def test_compact_many_bit_identical_and_batched(tmp_path):
         p = str(tmp_path / ("%06d.sst" % no[0]))
         sstable.write_sst(p, img, no[0])
         return p
+    return make_sst
+
+
+def test_batch_signature_is_the_run_slot_class():
+    """(slots, slot blocks, bottom level): a power-of-two run count and
+    a power-of-two block count that holds the largest run -- the jobs of
+    a store at the paper's geometry (4 MiB SSTs of 1,024 blocks)."""
+    l0 = [250, 253, 253, 253, 1024, 788]
+    assert batch_signature(l0, False) == (8, 1024, False)
+    assert batch_signature([253] * 4 + [1024, 770], False) == \
+        (8, 1024, False)
+    assert batch_signature([1024] * 14, True) == \
+        batch_signature([1024] * 15, True) == (16, 1024, True)
+    assert batch_signature([1024] * 17, True) == (32, 1024, True)
+    assert batch_signature([256] * 4, False) == (4, 256, False)
+    assert batch_signature([256, 257], False) == (2, 512, False)
+    assert batch_signature([7], True) == (1, 8, True)
+
+
+def test_compact_many_bit_identical_and_batched(tmp_path):
+    """compact_many must (a) coalesce >=2 same-bucket jobs into one
+    stacked launch and (b) emit output bit-identical to sequential
+    per-job compact_paths."""
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+
+    eng = DeviceCompactionEngine(GEOM)
+    rng = np.random.default_rng(3)
+    make_sst = _sst_writer(eng, tmp_path, rng)
 
     # 3 jobs: two share a shape bucket, one is bigger (own bucket)
     jobs = [([make_sst(b"a", 25), make_sst(b"a", 30)], False),
@@ -185,28 +208,12 @@ def test_compact_many_bit_identical_and_batched(tmp_path):
 
 def test_compact_many_isolates_per_job_crc_verdicts(tmp_path):
     """A corrupt input must fail ITS job only -- batch mates still verify."""
-    from repro.core import formats
     from repro.lsm import sstable
     from repro.lsm.cpu_engine import DeviceCompactionEngine
 
     eng = DeviceCompactionEngine(GEOM)
     rng = np.random.default_rng(5)
-    no = [0]
-
-    def make_sst(prefix, n):
-        keys = sorted(prefix + b"key%04d" % int(x)
-                      for x in rng.choice(2000, n, replace=False))
-        karr = np.stack([formats.pack_key_bytes(k, GEOM.key_bytes)
-                         for k in keys])
-        meta = np.array([(i + 1) << 1 | 1 for i in range(n)], np.uint32)
-        vals = np.stack([formats.pack_value_bytes(b"v%d" % i,
-                                                  GEOM.value_bytes)
-                         for i in range(n)])
-        img = eng.build_image(karr, meta, vals)
-        no[0] += 1
-        p = str(tmp_path / ("%06d.sst" % no[0]))
-        sstable.write_sst(p, img, no[0])
-        return p
+    make_sst = _sst_writer(eng, tmp_path, rng)
 
     jobs = [([make_sst(b"a", 25), make_sst(b"a", 30)], False),
             ([make_sst(b"b", 26), make_sst(b"b", 29)], False)]
@@ -221,6 +228,30 @@ def test_compact_many_isolates_per_job_crc_verdicts(tmp_path):
     assert results[0][1].crc_ok is True
     assert results[1][1].crc_ok is False
     assert eng.max_batch_jobs >= 2   # they still rode one launch
+
+
+def test_compact_many_stacks_jobs_of_one_slot_class(tmp_path):
+    """Jobs with other run counts and lengths but one run-slot class
+    (at most 4 runs of at most 4 blocks) ride one stacked launch, laid
+    out in one image, bit-identical to the single-job path."""
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+
+    eng = DeviceCompactionEngine(GEOM)
+    make_sst = _sst_writer(eng, tmp_path, np.random.default_rng(9))
+    jobs = [([make_sst(b"a", 60), make_sst(b"a", 30), make_sst(b"a", 17)],
+             False),
+            ([make_sst(b"b", 64), make_sst(b"b", 5), make_sst(b"b", 40),
+              make_sst(b"b", 64)], False)]
+    seq = [eng.compact_paths(p, bottom_level=b) for p, b in jobs]
+    launches0 = eng.batch_launches
+    batched = eng.compact_many(jobs)
+    assert eng.batch_launches == launches0 + 1
+    assert ("batch", 2, 4, 4, False) in eng.jit_signature_counts
+    for (o1, s1), (o2, s2) in zip(seq, batched):
+        assert s2.batched and (s1.n_live, s1.bytes_out) == \
+            (s2.n_live, s2.bytes_out)
+        for a, b, name in zip(o1, o2, o1._fields):
+            np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 def test_sharded_batches_cross_shard_jobs(tmp_path):
