@@ -715,8 +715,12 @@ class LsmDB:
         finally:
             # gets used to bump a plain field with no lock at all (get is
             # lock-free by design); the registry counter is atomic
+            dt = time.perf_counter_ns() - t0
             self._c["gets"].inc()
-            self._h_get.pend((time.perf_counter_ns() - t0) / 1000.0)
+            self._h_get.pend(dt / 1000.0)
+            tr = self.tracer
+            if tr.enabled:
+                tr.complete("db.get", t0, dt)
 
     def _get_inner(self, key: bytes, opts: ReadOptions):
         err = None
@@ -809,7 +813,7 @@ class LsmDB:
     def _table_get(self, fm: FileMeta, key: bytes,
                    opts: ReadOptions | None = None):
         found, value, pruned = self.cache.reader(fm, self.geom).probe(
-            key, opts)
+            key, opts, tracer=self.tracer)
         if pruned:
             self._c["bloom_negative_skips"].inc()
         return found, value

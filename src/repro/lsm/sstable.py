@@ -34,6 +34,7 @@ import dataclasses
 import os
 import struct
 import threading
+import time
 from collections import OrderedDict
 
 import numpy as np
@@ -41,6 +42,7 @@ import numpy as np
 from repro.core import formats
 from repro.core.formats import SSTGeometry, SSTImage
 from repro.lsm import faults
+from repro.obs.trace import NULL_TRACER
 
 MAGIC = b"LUDASST1"
 SENTINEL = np.uint32(0xFFFFFFFF)   # all-ones key: sorts after any real key
@@ -299,6 +301,12 @@ class TableReader:
         return fk
 
     @property
+    def opened(self) -> bool:
+        """Whether a read has opened the table (file read, first keys)."""
+        with self._lock:
+            return self._first_keys is not None
+
+    @property
     def n_blocks(self) -> int:
         return self._load().keys.shape[0]
 
@@ -380,12 +388,17 @@ class TableReader:
             return DEFAULT_READ_OPTIONS
         return opts
 
-    def probe(self, key: bytes, opts=None
+    def probe(self, key: bytes, opts=None, *, tracer=NULL_TRACER
               ) -> tuple[bool, bytes | None, bool]:
         """``(found, value|None, bloom_pruned)``: the tombstone-aware
         lookup.  ``found=True, value=None`` means a tombstone shadows the
         key; ``bloom_pruned=True`` means the filter proved absence without
-        decoding a block.
+        decoding a block.  With ``tracer`` enabled, the table's first
+        read (the whole file, its CRC and the blocks' first keys) is one
+        ``get.table_load`` span, and a block-cache miss one
+        ``get.block_load`` span (the block's decode, with its CRC check
+        when ``opts.verify_crc``); a cached table or block records
+        nothing.
 
         Searching ``keys_packed`` with the plain user key is exact:
         numpy ``S`` comparisons zero-pad the scalar to the item width,
@@ -396,7 +409,14 @@ class TableReader:
         from repro.lsm import cpu_engine as ce
         if not (self.meta.smallest <= key <= self.meta.largest):
             return False, None, False
-        b = self.candidate_block(key)
+        if tracer.enabled and not self.opened:
+            # the table's first read: whole file, its CRC, first keys
+            t0 = time.perf_counter_ns()
+            b = self.candidate_block(key)
+            tracer.complete("get.table_load", t0,
+                            time.perf_counter_ns() - t0)
+        else:
+            b = self.candidate_block(key)
         blk = self.cached_block(b)
         if blk is None:
             # bloom-probe only when the block is NOT already decoded: a
@@ -410,8 +430,12 @@ class TableReader:
                                         self.geom.bloom_probes)
                 if not bool(hit[0, 0]):
                     return False, None, True
+            t0 = time.perf_counter_ns() if tracer.enabled else 0
             blk = self.decode_block(b, fill_cache=opts.fill_cache,
                                     verify_crc=opts.verify_crc)
+            if tracer.enabled:
+                tracer.complete("get.block_load", t0,
+                                time.perf_counter_ns() - t0)
         i = int(np.searchsorted(blk.keys_packed, key))
         if i >= blk.nvalid or blk.keys_packed[i] != key:
             return False, None, False

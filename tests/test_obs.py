@@ -530,6 +530,74 @@ def test_sharded_trace_has_stacked_launch(tmp_path):
     db.close()
 
 
+GET_SPANS = ("db.get", "get.table_load", "get.block_load")
+
+
+def _get_spans(tr: Tracer) -> list[dict]:
+    return [e for e in tr.to_chrome()["traceEvents"]
+            if e.get("ph") == "X" and e["name"] in GET_SPANS]
+
+
+def _traced_get(db, tr: Tracer, key: bytes):
+    """One ``get`` on a cleared tracer: its value, the count of each get
+    span it recorded, the spans, and the block-cache misses it made."""
+    tr.clear()
+    misses = db.stats.block_cache_misses
+    value = db.get(key)
+    spans = _get_spans(tr)
+    counts = {n: sum(e["name"] == n for e in spans) for n in GET_SPANS}
+    return value, counts, spans, db.stats.block_cache_misses - misses
+
+
+def test_get_spans_follow_the_read_path(tmp_path):
+    """One ``db.get`` span per call; ``get.table_load`` only for the
+    first read of a table the table cache did not hold; ``get.block_load``
+    once per block-cache miss, both nested in ``db.get``; none of the two
+    for a second get of the same key."""
+    tr = Tracer()
+    db = LsmDB(str(tmp_path / "db"), obs_cfg(), tracer=tr)
+    for i in range(40):
+        db.put(b"key%04d" % i, b"v%04d" % i)
+    db.flush()
+    db.put(b"key9999", b"mem")
+
+    value, counts, _, misses = _traced_get(db, tr, b"key9999")
+    assert value == b"mem" and misses == 0
+    assert counts == {"db.get": 1, "get.table_load": 0, "get.block_load": 0}
+
+    value, counts, spans, misses = _traced_get(db, tr, b"key0007")
+    assert value == b"v0007" and misses == 1
+    assert counts == {"db.get": 1, "get.table_load": 1, "get.block_load": 1}
+    by_name = {e["name"]: e for e in spans}
+    assert _inside(by_name["get.table_load"], [by_name["db.get"]])
+    assert _inside(by_name["get.block_load"], [by_name["db.get"]])
+    assert (by_name["get.table_load"]["ts"] + by_name["get.table_load"]["dur"]
+            <= by_name["get.block_load"]["ts"] + 1e-6)
+
+    value, counts, _, misses = _traced_get(db, tr, b"key0007")
+    assert value == b"v0007" and misses == 0
+    assert counts == {"db.get": 1, "get.table_load": 0, "get.block_load": 0}
+
+    # another block of the open table: a block load, no table load
+    value, counts, _, misses = _traced_get(db, tr, b"key0039")
+    assert value == b"v0039" and misses == 1
+    assert counts == {"db.get": 1, "get.table_load": 0, "get.block_load": 1}
+    db.close()
+
+
+def test_get_records_no_span_untraced(tmp_path):
+    tr = Tracer()
+    tr.enabled = False
+    db = LsmDB(str(tmp_path / "db"), obs_cfg(), tracer=tr)
+    for i in range(40):
+        db.put(b"key%04d" % i, b"v%04d" % i)
+    db.flush()
+    assert db.get(b"key0007") == b"v0007"
+    assert db.stats.block_cache_misses >= 1
+    assert not _get_spans(tr)
+    db.close()
+
+
 @pytest.mark.skipif(bool(os.environ.get("REPRO_SANITIZE")),
                     reason="sanitizer __setattr__ interception dominates the "
                            "put path; perf assertion meaningless under it")
