@@ -4,9 +4,9 @@ The device paths key their jit caches by input *shape*; feeding raw
 data-dependent shapes into a jitted entry point compiles once per
 distinct shape and fragments the cache (the silent 100x slowdown class).
 The repo's contract is pow2 bucketing before dispatch --
-``scheduler.batch_signature`` / ``read._bucket`` / ``offload.next_pow2``
-/ ``offload.run_slots`` -- so every call site of a jitted entry point
-must show bucketing evidence in its enclosing function.
+``read._bucket`` / ``offload.next_pow2`` / ``offload.run_slots`` -- so
+every call site of a jitted entry point must show bucketing evidence in
+its enclosing function.
 
 Rule:
 
@@ -36,7 +36,7 @@ ENTRY_POINTS = {
 # any reference to one of these names counts as bucketing evidence
 BUCKET_HELPERS = {
     "next_pow2", "round_up", "_bucket", "bucket", "run_slots",
-    "slot_layout", "batch_signature", "bucket_blocks", "pad_to_bucket",
+    "slot_layout", "bucket_blocks", "pad_to_bucket",
 }
 
 

@@ -128,21 +128,21 @@ def sort_phase(rows: jax.Array, *, sort_mode: str, backend: str = "auto",
     raise ValueError(f"unknown sort_mode {sort_mode!r}")
 
 
-def survivor_mask(rows: jax.Array, valid: jax.Array, key_lanes: int, *,
-                  bottom_level: bool) -> jax.Array:
+def survivor_mask(rows: jax.Array, valid: jax.Array, key_lanes: int,
+                  bottom_level) -> jax.Array:
     """Phase-2 delete logic on sorted tuples: keep the newest version of
     each user key; drop shadowed versions; collect tombstones only at the
-    bottom level (older levels must keep them to shadow deeper data)."""
+    bottom level (older levels must keep them to shadow deeper data).
+    ``bottom_level`` is a traced boolean, so one program serves jobs at
+    every level."""
     keys_s = rows[:, :key_lanes]
     meta = ~rows[:, key_lanes]
     idx = rows[:, key_lanes + 1].astype(jnp.int32)
     valid_s = valid[idx]
     neq_prev = jnp.any(keys_s != jnp.roll(keys_s, 1, axis=0), axis=1)
     first = neq_prev | (jnp.arange(rows.shape[0]) == 0)
-    live = valid_s & first
-    if bottom_level:
-        live = live & formats.meta_is_value(meta)
-    return live
+    return valid_s & first & (formats.meta_is_value(meta)
+                              | jnp.logical_not(bottom_level))
 
 
 # ---------------------------------------------------------------------------
@@ -199,11 +199,7 @@ def pack(rows: jax.Array, live: jax.Array, vals: jax.Array,
     crc = ops.crc32_sections(formats.wire_sections(img), backend=backend)
 
     # filter kernel: bloom per block or per SST on *restored* keys
-    if geom.bloom_granularity == "block":
-        groups, per = n_blocks, k
-    else:
-        per = min(geom.sst_kvs, n)
-        groups = n // per
+    groups, per = geom.filter_groups(n)
     gk = keys_c.reshape(groups, per, lanes)
     gv = valid_c.reshape(groups, per)
     bloom = ops.bloom_build(gk, gv.astype(jnp.uint32),
@@ -217,14 +213,16 @@ def pack(rows: jax.Array, live: jax.Array, vals: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("geom", "bottom_level",
-                                             "sort_mode", "backend",
-                                             "run_lens"))
-def compact(img: SSTImage, *, geom: SSTGeometry, bottom_level: bool = False,
+@functools.partial(jax.jit, static_argnames=("geom", "sort_mode",
+                                             "backend", "run_lens"))
+def compact(img: SSTImage, bottom_level=False, *, geom: SSTGeometry,
             sort_mode: str = "device", backend: str = "auto",
             run_lens: tuple[int, ...] | None = None
             ) -> tuple[SSTImage, CompactionStats]:
     """Run one full compaction over the concatenated input image.
+
+    ``bottom_level`` (traced boolean: tombstones are collected) is data,
+    not part of the jit cache key.
 
     ``run_lens`` (static, entries per input SST; only consumed by
     ``sort_mode="merge"``) preserves the sorted-run structure of the
@@ -248,8 +246,7 @@ def compact(img: SSTImage, *, geom: SSTGeometry, bottom_level: bool = False,
         rows_s = sort_phase(rows, sort_mode=sort_mode, backend=backend,
                             run_lens=run_lens)
     with jax.named_scope("survivors"):
-        live = survivor_mask(rows_s, up.valid, geom.key_lanes,
-                             bottom_level=bottom_level)
+        live = survivor_mask(rows_s, up.valid, geom.key_lanes, bottom_level)
     with jax.named_scope("pack"):
         out = pack(rows_s, live, up.vals, geom, backend=backend)
 

@@ -92,6 +92,15 @@ class SSTGeometry:
         bits = max(64, keys_per_group * self.bloom_bits_per_key)
         return (bits + 31) // 32
 
+    def filter_groups(self, n_rows: int) -> tuple[int, int]:
+        """``(groups, keys per group)`` of the filter block over an image
+        of ``n_rows`` entries: one filter per block, or one per SST's
+        worth of entries (the whole image when it holds less)."""
+        if self.bloom_granularity == "block":
+            return n_rows // self.block_kvs, self.block_kvs
+        per = min(self.sst_kvs, n_rows)
+        return n_rows // per, per
+
     @property
     def wire_words_per_block(self) -> int:
         """uint32 words per block covered by the CRC (header + payload)."""

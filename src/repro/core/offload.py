@@ -21,6 +21,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -76,7 +77,7 @@ class CompactionExecutor:
         if self.debug_check_runs and self.sort_mode == "merge":
             self._check_runs(img, run_lens)
         return compaction.compact(
-            img, geom=self.geom, bottom_level=bottom_level,
+            img, np.bool_(bottom_level), geom=self.geom,
             sort_mode=self.sort_mode, backend=self.backend,
             run_lens=run_lens if self.sort_mode == "merge" else None)
 
@@ -89,18 +90,19 @@ class CompactionExecutor:
         merge_path.assert_runs_sorted(rows, run_lens)
 
     def launch_many(self, img: SSTImage, run_lens: tuple[int, ...], *,
-                    bottom_level: bool = False
+                    bottom_levels
                     ) -> tuple[SSTImage, compaction.CompactionStats]:
         """Several jobs in one stacked device launch: ``img`` is
-        ``slot_layout``'s image of J jobs, every field ``[J, ...]``.
-        Returns the stacked output image and per-job stats, each lane
-        bit-identical to ``launch`` of that job alone: ``vmap`` runs the
-        same integer pipeline per batch lane."""
+        ``slot_layout``'s image of J jobs, every field ``[J, ...]``, and
+        ``bottom_levels`` the J jobs' bottom-level flags.  Returns the
+        stacked output image and per-job stats, each lane bit-identical
+        to ``launch`` of that job alone: ``vmap`` runs the same integer
+        pipeline per batch lane."""
         if self.debug_check_runs and self.sort_mode == "merge":
             for j in range(img.keys.shape[0]):
                 self._check_runs(SSTImage(*(a[j] for a in img)), run_lens)
         return compact_batch(
-            img, geom=self.geom, bottom_level=bottom_level,
+            img, np.asarray(bottom_levels, np.bool_), geom=self.geom,
             sort_mode=self.sort_mode, backend=self.backend,
             run_lens=run_lens if self.sort_mode == "merge" else None)
 
@@ -126,29 +128,27 @@ class CompactionExecutor:
                            backend=self.backend)
 
 
-@functools.partial(jax.jit, static_argnames=("geom", "bottom_level",
-                                             "sort_mode", "backend",
-                                             "run_lens"))
-def compact_batch(img: SSTImage, *, geom: SSTGeometry,
-                  bottom_level: bool = False, sort_mode: str = "device",
-                  backend: str = "auto",
+@functools.partial(jax.jit, static_argnames=("geom", "sort_mode",
+                                             "backend", "run_lens"))
+def compact_batch(img: SSTImage, bottom_level, *, geom: SSTGeometry,
+                  sort_mode: str = "device", backend: str = "auto",
                   run_lens: tuple[int, ...] | None = None):
     """One stacked device launch over a leading *job* axis.
 
     ``img`` holds J independent compaction jobs stacked on axis 0 (every
-    field is ``[J, ...]`` of one job's shape).  Compaction procedures are
-    data-independent (the paper's core scaling argument), so the whole
-    batch is a single ``vmap`` over the job axis: one dispatch, one jit
-    cache entry per (job count, run-slot layout, bottom level), J jobs of
-    occupancy.
+    field is ``[J, ...]`` of one job's shape), ``bottom_level`` their J
+    boolean flags.  Compaction procedures are data-independent (the
+    paper's core scaling argument), so the whole batch is a single
+    ``vmap`` over the job axis: one dispatch, one jit cache entry per
+    (job count, run-slot layout), J jobs of occupancy.
     Returns the stacked output image plus per-job ``CompactionStats``
     (``crc_ok`` stays a per-job verdict -- one corrupt input must not
     taint its batch mates)."""
-    def one(im: SSTImage):
+    def one(im: SSTImage, bottom):
         return compaction.compact(
-            im, geom=geom, bottom_level=bottom_level, sort_mode=sort_mode,
-            backend=backend, run_lens=run_lens)
-    return jax.vmap(one)(img)
+            im, bottom, geom=geom, sort_mode=sort_mode, backend=backend,
+            run_lens=run_lens)
+    return jax.vmap(one)(img, bottom_level)
 
 
 @functools.partial(jax.jit, static_argnames=("geom", "backend"))
@@ -181,7 +181,10 @@ def run_slots(block_counts) -> tuple[int, int]:
     """``(slots, slot_blocks)`` of a job's run-slot layout: a power-of-two
     number of slots, one per input run, each a power-of-two number of
     blocks that holds the largest run.  The jit signature of a launch
-    depends on this pair (and the bottom level) alone."""
+    depends on this pair alone: jobs of one class present identical
+    array shapes and identical static ``run_lens``, so they reuse one
+    compiled program, and several of them stack into one vmapped launch
+    (``DeviceCompactionEngine.compact_many``)."""
     return next_pow2(len(block_counts)), next_pow2(max(block_counts))
 
 
@@ -199,8 +202,6 @@ def slot_layout(jobs: list[list[SSTImage]], geom: SSTGeometry, slots: int,
     ``build_tuples`` and sort last inside their slot, so every slot is a
     sorted run and the merge stays exact.  The input filters are not
     read by the pipeline: ``bloom`` is a ``[J, 1, 1]`` placeholder."""
-    import numpy as np
-
     from repro.kernels import tables
     total = slots * slot_blocks
     k, lanes, vw = geom.block_kvs, geom.key_lanes, geom.value_words
@@ -252,8 +253,8 @@ def sharded_compact(img: SSTImage, mesh: Mesh, axes, *, geom: SSTGeometry,
 
     def per_shard(im: SSTImage):
         out, stats = compaction.compact(
-            im, geom=geom, bottom_level=bottom_level,
-            sort_mode=sort_mode, backend=backend)
+            im, bottom_level, geom=geom, sort_mode=sort_mode,
+            backend=backend)
         stats = jax.tree.map(lambda x: x.reshape(1, *jnp.shape(x)), stats)
         return out, stats
 

@@ -38,21 +38,6 @@ class CompactionJob:
         return sum(f.size_bytes for f in self.all_inputs)
 
 
-def batch_signature(block_counts, bottom_level: bool) -> tuple:
-    """The jit signature of a device launch: ``(slots, slot_blocks,
-    bottom_level)``.
-
-    ``block_counts`` are the per-input SST block counts of one job.  The
-    engine lays the job's runs out one per slot (``offload.run_slots``:
-    a power-of-two slot count, each slot a power-of-two block count that
-    holds the largest run), so jobs with equal signatures present
-    identical array shapes and identical static ``run_lens``: they reuse
-    one compiled program, and several of them stack into one vmapped
-    launch (``DeviceCompactionEngine.compact_many``)."""
-    from repro.core.offload import run_slots
-    return (*run_slots(block_counts), bool(bottom_level))
-
-
 @dataclasses.dataclass
 class SchedulerConfig:
     l0_trigger: int = 4

@@ -60,16 +60,16 @@ def crc32_words_sections(sections) -> jax.Array:
 # Bloom filter (LevelDB-style double hashing, 32-bit FNV/murmur mix)
 # ---------------------------------------------------------------------------
 
-_FNV_OFFSET = np.uint32(2166136261)
-_FNV_PRIME = np.uint32(16777619)
+_FNV_OFFSET = np.uint32(tables.FNV_OFFSET)
+_FNV_PRIME = np.uint32(tables.FNV_PRIME)
 
 
 def _mix32(h: jax.Array) -> jax.Array:
     """murmur3 fmix32 finalizer."""
     h = h ^ (h >> jnp.uint32(16))
-    h = h * jnp.uint32(0x85EBCA6B)
+    h = h * jnp.uint32(tables.MIX_1)
     h = h ^ (h >> jnp.uint32(13))
-    h = h * jnp.uint32(0xC2B2AE35)
+    h = h * jnp.uint32(tables.MIX_2)
     h = h ^ (h >> jnp.uint32(16))
     return h
 
@@ -85,11 +85,11 @@ def bloom_hashes_lanes(lanes: list) -> tuple[jax.Array, jax.Array]:
     """``bloom_hashes`` over keys given as one uint32 array per key lane
     (the layout the Pallas kernels hold them in)."""
     h1 = jnp.full(lanes[0].shape, _FNV_OFFSET, jnp.uint32)
-    h2 = jnp.full(lanes[0].shape, _FNV_OFFSET ^ jnp.uint32(0xDEADBEEF),
+    h2 = jnp.full(lanes[0].shape, _FNV_OFFSET ^ jnp.uint32(tables.H2_SEED),
                   jnp.uint32)
     for lane in lanes:
         h1 = (h1 ^ lane) * _FNV_PRIME
-        h2 = (h2 ^ jnp.uint32(0x9E3779B9) ^ lane) * _FNV_PRIME
+        h2 = (h2 ^ jnp.uint32(tables.H2_SALT) ^ lane) * _FNV_PRIME
     h1 = _mix32(h1)
     h2 = _mix32(h2) | jnp.uint32(1)  # odd delta: full-period double hashing
     return h1, h2

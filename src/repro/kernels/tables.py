@@ -20,6 +20,9 @@ the VPU idle; gathers from a 256-entry table are pathological on TPU).
 
 The table only depends on the message length, so it is computed once per
 block geometry on the host (numpy + binascii, exact) and cached.
+
+The constants of the filter hash live here too, for the kernels
+(``ref.py``) and the host's numpy and scalar copies (``lsm/cpu_engine.py``).
 """
 
 from __future__ import annotations
@@ -28,6 +31,15 @@ import binascii
 import functools
 
 import numpy as np
+
+# The filter hash: two FNV-1a lanes over the key's big-endian uint32
+# words (the second seeded and salted), each finished by murmur3's
+# ``fmix32`` multipliers; probe ``i`` sets bit ``(h1 + i * h2) mod m``.
+FNV_OFFSET = 2166136261
+FNV_PRIME = 16777619
+H2_SEED = 0xDEADBEEF
+H2_SALT = 0x9E3779B9
+MIX_1, MIX_2 = 0x85EBCA6B, 0xC2B2AE35
 
 
 @functools.lru_cache(maxsize=64)

@@ -16,11 +16,14 @@ from __future__ import annotations
 import binascii
 import collections
 import dataclasses
+import struct
 import time
 
 import numpy as np
 
 from repro.core.formats import SSTGeometry, SSTImage
+from repro.kernels.tables import (FNV_OFFSET, FNV_PRIME, H2_SALT, H2_SEED,
+                                  MIX_1, MIX_2)
 from repro.lsm import faults
 from repro.obs.trace import NULL_TRACER
 
@@ -82,22 +85,27 @@ def np_crc_blocks(words: np.ndarray) -> np.ndarray:
                      for row in words], dtype=np.uint32)
 
 
+# The filter hash of ``kernels/ref.py``, on its constants: the numpy
+# functions below and the scalar pair after them.
+_MASK = 0xFFFFFFFF
+
+
 def _np_mix32(h):
     h = h ^ (h >> U32(16))
-    h = (h * U32(0x85EBCA6B)).astype(U32)
+    h = (h * U32(MIX_1)).astype(U32)
     h = h ^ (h >> U32(13))
-    h = (h * U32(0xC2B2AE35)).astype(U32)
+    h = (h * U32(MIX_2)).astype(U32)
     return h ^ (h >> U32(16))
 
 
 def np_bloom_hashes(keys: np.ndarray):
     keys = keys.astype(U32)
-    h1 = np.full(keys.shape[:-1], 2166136261, U32)
-    h2 = np.full(keys.shape[:-1], 2166136261 ^ 0xDEADBEEF, U32)
+    h1 = np.full(keys.shape[:-1], FNV_OFFSET, U32)
+    h2 = np.full(keys.shape[:-1], FNV_OFFSET ^ H2_SEED, U32)
     for lane in range(keys.shape[-1]):
-        h1 = ((h1 ^ keys[..., lane]) * U32(16777619)).astype(U32)
-        h2 = ((h2 ^ U32(0x9E3779B9) ^ keys[..., lane]) *
-              U32(16777619)).astype(U32)
+        h1 = ((h1 ^ keys[..., lane]) * U32(FNV_PRIME)).astype(U32)
+        h2 = ((h2 ^ U32(H2_SALT) ^ keys[..., lane]) *
+              U32(FNV_PRIME)).astype(U32)
     return _np_mix32(h1), _np_mix32(h2) | U32(1)
 
 
@@ -128,6 +136,38 @@ def np_bloom_query(filters: np.ndarray, keys: np.ndarray,
                                   axis=-1)
         ok &= ((word >> (pos & U32(31))) & 1).astype(bool)
     return ok
+
+
+def _mix32(h: int) -> int:
+    h ^= h >> 16
+    h = (h * MIX_1) & _MASK
+    h ^= h >> 13
+    h = (h * MIX_2) & _MASK
+    return h ^ (h >> 16)
+
+
+def bloom_hashes_of(key: bytes, key_bytes: int) -> tuple[int, int]:
+    """``np_bloom_hashes`` of one user key, in Python integers: the
+    scalar get hashes a key once and checks every table's filter row
+    with it (``bloom_row_may_contain``)."""
+    h1, h2 = FNV_OFFSET, FNV_OFFSET ^ H2_SEED
+    for w in struct.unpack(f">{key_bytes // 4}I",
+                           key.ljust(key_bytes, b"\x00")):
+        h1 = ((h1 ^ w) * FNV_PRIME) & _MASK
+        h2 = ((h2 ^ H2_SALT ^ w) * FNV_PRIME) & _MASK
+    return _mix32(h1), _mix32(h2) | 1
+
+
+def bloom_row_may_contain(row: np.ndarray, h1: int, h2: int,
+                          n_probes: int) -> bool:
+    """``np_bloom_query`` of one key against one filter row, reading the
+    row's words in place: False proves the key absent."""
+    m_bits = row.shape[-1] * 32
+    for i in range(n_probes):
+        pos = ((h1 + i * h2) & _MASK) % m_bits
+        if not (row.item(pos >> 5) >> (pos & 31)) & 1:
+            return False
+    return True
 
 
 def np_wire_words(img: SSTImage) -> np.ndarray:
@@ -333,17 +373,21 @@ class CpuCompactionEngine:
             shared=shared.reshape(nb, k), nvalid=nvalid,
             crc=np.zeros(nb, U32), bloom=np.zeros((1, 1), U32))
         crc = np_crc_blocks(np_wire_words(img))
-        if g.bloom_granularity == "block":
-            groups, per = nb, k
-        else:
-            per = min(g.sst_kvs, n_pad)
-            groups = n_pad // per
+        groups, per = g.filter_groups(n_pad)
         bloom = np_bloom_build(keys.reshape(groups, per, g.key_lanes),
                                valid.reshape(groups, per),
                                g.bloom_words(per), g.bloom_probes)
         return SSTImage(keys=img.keys, meta=img.meta, vals=img.vals,
                         shared=img.shared, nvalid=img.nvalid, crc=crc,
                         bloom=bloom)
+
+
+#: a job of a run-slot class the engine never launched runs in a
+#: launched class of at most this many times its blocks; a wider launch
+#: would merge, lay out and copy back more padding than a compile costs
+#: a long-running store (a class compiles once per process, or loads
+#: from the persistent compile cache)
+MAX_CLASS_WIDENING = 2
 
 
 class DeviceCompactionEngine:
@@ -362,12 +406,17 @@ class DeviceCompactionEngine:
         self._reader = None
         # jit cache bookkeeping: every job is laid out in run slots
         # (``offload.run_slots``), so jobs of one slot class reuse the
-        # compiled program.  The key is the launch's jit signature (slots,
-        # slot blocks, bottom level, and the job count of a stacked
-        # launch); a miss = first launch of a signature.
+        # compiled program.  The key is the launch's jit signature:
+        # ``("one", slots, slot_blocks)``, or ``("batch", jobs, slots,
+        # slot_blocks)`` for a stacked launch; a miss = first launch of a
+        # signature.  A job whose own class was never launched runs in
+        # the smallest launched class that holds it, at most
+        # ``MAX_CLASS_WIDENING`` times its size (``_launch_class``);
+        # ``class_reuses`` counts those jobs.
         self.jit_signature_counts: dict[tuple, int] = {}
         self.jit_bucket_hits = 0
         self.jit_bucket_misses = 0
+        self.class_reuses = 0
         # batched-launch accounting (compact_many): one "launch" is one
         # stacked vmapped dispatch covering >=2 same-signature jobs
         self.batch_launches = 0
@@ -399,6 +448,39 @@ class DeviceCompactionEngine:
         else:
             self.jit_bucket_misses += 1
         return bool(seen)
+
+    def _launch_class(self, kind: tuple, cls: tuple[int, int]
+                      ) -> tuple[int, int]:
+        """The run-slot class to launch a job of class ``cls`` in, as
+        ``kind`` (``("one",)``, or ``("batch", jobs)``): ``cls`` itself
+        if it was launched so before or no launched class holds the job,
+        else the smallest launched class with at least as many slots and
+        blocks per slot, at most ``MAX_CLASS_WIDENING`` times ``cls``'s
+        blocks, whose filter groups hold as many keys as ``cls``'s.  Its
+        extra slots and blocks are sentinel runs, so its output cut to
+        ``cls`` (``_cut``) is ``cls``'s own, byte for byte, and no
+        program is compiled for ``cls``."""
+        if kind + cls in self.jit_signature_counts:
+            return cls
+        slots, blocks = cls
+        k = self.geom.block_kvs
+        per = self.geom.filter_groups(slots * blocks * k)[1]
+        held = sorted(
+            (s * b, s, b) for *head, s, b in self.jit_signature_counts
+            if tuple(head) == kind and s >= slots and b >= blocks
+            and s * b <= MAX_CLASS_WIDENING * slots * blocks
+            and self.geom.filter_groups(s * b * k)[1] == per)
+        return held[0][1:] if held else cls
+
+    def _cut(self, out: SSTImage, cls: tuple[int, int], batched: bool
+             ) -> SSTImage:
+        """A launch's output cut to the blocks and filter groups of the
+        job's own class ``cls`` (a no-op when it ran in that class)."""
+        n = cls[0] * cls[1]
+        groups = self.geom.filter_groups(n * self.geom.block_kvs)[0]
+        lead = (slice(None),) if batched else ()
+        return SSTImage(*(a[lead + (slice(n),)] for a in out[:-1]),
+                        bloom=out.bloom[lead + (slice(groups),)])
 
     def _cpu_engine(self) -> CpuCompactionEngine:
         """The lazily-built degraded-mode twin (bit-identical output)."""
@@ -446,13 +528,14 @@ class DeviceCompactionEngine:
 
     def compact(self, images, *, bottom_level: bool = False):
         def attempt():
-            from repro.core.scheduler import batch_signature
+            from repro.core.offload import run_slots
             t0 = time.perf_counter()  # layout and H2D staging are host work
             blocks = [im.keys.shape[0] for im in images]
-            sig = batch_signature(blocks, bottom_level)
-            staged = self.executor.stage([images], *sig[:2])
-            return self._compact_staged(staged, sig, real_blocks=sum(blocks),
-                                        t0=t0)
+            cls = run_slots(blocks)
+            launch = self._launch_class(("one",), cls)
+            staged = self.executor.stage([images], *launch)
+            return self._compact_staged(staged, cls, launch, bottom_level,
+                                        real_blocks=sum(blocks), t0=t0)
 
         return self._with_fallback(
             attempt,
@@ -465,7 +548,7 @@ class DeviceCompactionEngine:
         their run slots on the host and staged to the device once."""
         def attempt():
             from repro.core.background import PrefetchReader
-            from repro.core.scheduler import batch_signature
+            from repro.core.offload import run_slots
             from repro.lsm import sstable
             t0 = time.perf_counter()
             if self._reader is None:
@@ -474,11 +557,12 @@ class DeviceCompactionEngine:
                                   files=len(paths)) as sp:
                 imgs = list(self._reader.read_all(paths, sstable.read_sst))
                 blocks = [im.keys.shape[0] for im in imgs]
-                sig = batch_signature(blocks, bottom_level)
-                staged = self.executor.stage([imgs], *sig[:2])
+                cls = run_slots(blocks)
+                launch = self._launch_class(("one",), cls)
+                staged = self.executor.stage([imgs], *launch)
                 sp.set(h2d_bytes=staged[2])
-            return self._compact_staged(staged, sig, real_blocks=sum(blocks),
-                                        t0=t0)
+            return self._compact_staged(staged, cls, launch, bottom_level,
+                                        real_blocks=sum(blocks), t0=t0)
 
         return self._with_fallback(
             attempt,
@@ -487,21 +571,22 @@ class DeviceCompactionEngine:
 
     def compact_many(self, jobs: list[tuple[list[str], bool]]
                      ) -> list[tuple[SSTImage, EngineStats]]:
-        """Compact several independent jobs, coalescing jobs of one jit
-        signature into single stacked device launches.
+        """Compact several independent jobs, coalescing jobs of one
+        run-slot class into single stacked device launches.
 
         ``jobs``: ``[(input_paths, bottom_level)]`` -- typically one job
         per shard, published by ``ShardedDB``'s global queue.  Jobs are
-        grouped by ``scheduler.batch_signature`` (run-slot layout and
-        bottom level) of their *actual* input block counts; each group is
-        laid out and staged as one image.  A >=2-job group becomes ONE
-        vmapped dispatch (``offload.compact_batch``) with per-job CRC
-        verdicts; singleton groups take the ordinary single-job path.
-        Results come back in input order and are bit-identical to per-job
+        grouped by ``offload.run_slots`` of their *actual* input block
+        counts (the bottom level is a per-job flag of the launch); each
+        group is laid out, in its launch class (``_launch_class``), and
+        staged as one image.  A >=2-job group becomes ONE vmapped
+        dispatch (``offload.compact_batch``) with per-job CRC verdicts;
+        singleton groups take the ordinary single-job path.  Results come
+        back in input order and are bit-identical to per-job
         ``compact_paths``.
         """
         from repro.core.background import PrefetchReader
-        from repro.core.scheduler import batch_signature
+        from repro.core.offload import run_slots
         from repro.lsm import sstable
         t_many0 = time.perf_counter_ns()
         t_read0 = time.perf_counter()
@@ -517,23 +602,33 @@ class DeviceCompactionEngine:
                 imgs = flat_imgs[off:off + len(paths)]
                 off += len(paths)
                 job_imgs.append(imgs)
-                sig = batch_signature([im.keys.shape[0] for im in imgs],
-                                      bottom)
-                groups.setdefault(sig, []).append(j)
-            staged = {sig: self.executor.stage([job_imgs[j] for j in idxs],
-                                               *sig[:2])
-                      for sig, idxs in groups.items()}
+                cls = run_slots([im.keys.shape[0] for im in imgs])
+                groups.setdefault(cls, []).append(j)
+            launches = {
+                cls: self._launch_class(
+                    ("one",) if len(idxs) == 1 else ("batch", len(idxs)),
+                    cls)
+                for cls, idxs in groups.items()}
+            staged = {cls: self.executor.stage([job_imgs[j] for j in idxs],
+                                               *launches[cls])
+                      for cls, idxs in groups.items()}
             sp.set(h2d_bytes=sum(st[2] for st in staged.values()))
         read_share = (time.perf_counter() - t_read0) / max(1, len(jobs))
         results: list = [None] * len(jobs)
 
-        def single(j, sig, st=None):
-            """One prefetched job through the device path (+ fallback)."""
+        def single(j, cls, staged_at=None):
+            """One prefetched job through the device path (+ fallback);
+            ``staged_at`` is ``(staged, launch class)`` of a job the read
+            phase laid out already."""
             def attempt():
                 t0 = time.perf_counter()
-                staged = st or self.executor.stage([job_imgs[j]], *sig[:2])
+                if staged_at is None:
+                    launch = self._launch_class(("one",), cls)
+                    staged = self.executor.stage([job_imgs[j]], *launch)
+                else:
+                    staged, launch = staged_at
                 out, es = self._compact_staged(
-                    staged, sig,
+                    staged, cls, launch, jobs[j][1],
                     real_blocks=sum(im.keys.shape[0] for im in job_imgs[j]),
                     t0=t0)
                 es.host_seconds += read_share
@@ -544,15 +639,16 @@ class DeviceCompactionEngine:
                 lambda: self._cpu_engine().compact(
                     job_imgs[j], bottom_level=jobs[j][1]))
 
-        for sig, idxs in groups.items():
-            st = staged.pop(sig)
+        for cls, idxs in groups.items():
+            st = staged.pop(cls)
             if len(idxs) == 1:
-                results[idxs[0]] = single(idxs[0], sig, st)
+                results[idxs[0]] = single(idxs[0], cls,
+                                          (st, launches[cls]))
                 continue
             try:
                 results_group = self._compact_batched(
-                    st, sig, [job_imgs[j] for j in idxs],
-                    read_share=read_share)
+                    st, cls, launches[cls], [jobs[j][1] for j in idxs],
+                    [job_imgs[j] for j in idxs], read_share=read_share)
             except faults.FaultInjected as e:
                 # the stacked launch faulted: isolate by re-running the
                 # group's jobs one by one (device retry + CPU fallback
@@ -562,14 +658,14 @@ class DeviceCompactionEngine:
                 results_group = None
             if results_group is None:
                 for j in idxs:
-                    results[j] = single(j, sig)
+                    results[j] = single(j, cls)
             else:
                 for j, res in zip(idxs, results_group):
                     if not res[1].crc_ok:
                         # per-job negative verdict inside a batch: get an
                         # authoritative single-job verdict (still fails
                         # for genuinely corrupt inputs -- on the CPU)
-                        res = single(j, sig)
+                        res = single(j, cls)
                     results[j] = res
         if self.tracer.enabled:
             self.tracer.complete(
@@ -578,32 +674,40 @@ class DeviceCompactionEngine:
                 args={"jobs": len(jobs), "groups": len(groups)})
         return results
 
-    @staticmethod
-    def _launch_args(jobs: int, sig: tuple, hit: bool) -> dict:
-        slots, slot_blocks, _ = sig
-        return {"jobs": jobs, "bucket": slots * slot_blocks, "slots": slots,
-                "slot_blocks": slot_blocks, "sig_hit": hit}
+    def _launch_args(self, kind: tuple, jobs: int, cls: tuple[int, int],
+                     launch: tuple[int, int]) -> dict:
+        """Count a launch of ``jobs`` jobs of class ``cls`` in class
+        ``launch``; the launch span's args."""
+        slots, slot_blocks = launch
+        args = {"jobs": jobs, "bucket": slots * slot_blocks, "slots": slots,
+                "slot_blocks": slot_blocks,
+                "sig_hit": self._note_signature(kind + launch)}
+        if launch != cls:
+            self.class_reuses += jobs
+            args["reused_class"] = True
+        return args
 
-    def _sort_model(self, sig: tuple) -> float:
-        slots, slot_blocks, _ = sig
+    def _sort_model(self, launch: tuple[int, int]) -> float:
+        slots, slot_blocks = launch
         return model_sort_seconds(
             slots * slot_blocks * self.geom.block_kvs,
             self.geom.key_lanes + 2, slots, self.executor.sort_mode)
 
-    def _compact_batched(self, staged, sig, group_imgs, *, read_share):
-        """One stacked launch over >=2 jobs of one signature."""
+    def _compact_batched(self, staged, cls, launch, bottom_levels,
+                         group_imgs, *, read_share):
+        """One stacked launch over >=2 jobs of one run-slot class."""
         t0 = time.perf_counter()
         img, run_lens, _ = staged
-        bottom_level = sig[2]
         n_jobs = len(group_imgs)
-        hit = self._note_signature(("batch", n_jobs) + sig)
+        args = self._launch_args(("batch", n_jobs), n_jobs, cls, launch)
         self.batch_launches += 1
         self.batch_jobs += n_jobs
         self.max_batch_jobs = max(self.max_batch_jobs, n_jobs)
         (out, st), exec_wall = self._execute(
-            "compact.batch_launch", self._launch_args(n_jobs, sig, hit),
+            "compact.batch_launch", args,
             lambda: self.executor.launch_many(img, run_lens,
-                                              bottom_level=bottom_level))
+                                              bottom_levels=bottom_levels))
+        out = self._cut(out, cls, batched=True)
         host_share = max(time.perf_counter() - t0 - exec_wall, 0.0) / n_jobs
         wire = self.geom.wire_words_per_block * 4
         results = []
@@ -616,7 +720,7 @@ class DeviceCompactionEngine:
             stats.host_seconds = host_share + read_share
             stats.device_seconds = model_device_seconds(
                 stats.bytes_in, stats.bytes_out, self.geom)
-            stats.sort_seconds = self._sort_model(sig)
+            stats.sort_seconds = self._sort_model(launch)
             results.append((SSTImage(*(a[j] for a in out)), stats))
         return results
 
@@ -649,16 +753,17 @@ class DeviceCompactionEngine:
                         args={"bytes": sum(a.nbytes for a in out)})
         return (out, stats), (t4 - t0) / 1e9
 
-    def _compact_staged(self, staged, sig, *, real_blocks, t0):
+    def _compact_staged(self, staged, cls, launch, bottom_level, *,
+                        real_blocks, t0):
         img, run_lens, _ = staged
-        bottom_level = sig[2]
-        hit = self._note_signature(("one",) + sig)
+        args = self._launch_args(("one",), 1, cls, launch)
         # the launch is device work, not host coordination: its wall time
         # is kept out of host_seconds
         (out, s), exec_wall = self._execute(
-            "compact.execute", self._launch_args(1, sig, hit),
+            "compact.execute", args,
             lambda: self.executor.launch(img, run_lens,
                                          bottom_level=bottom_level))
+        out = self._cut(out, cls, batched=False)
         wire = self.geom.wire_words_per_block * 4
         stats = EngineStats(
             n_input=int(s.n_input), n_live=int(s.n_live),
@@ -667,7 +772,7 @@ class DeviceCompactionEngine:
         stats.host_seconds = max(time.perf_counter() - t0 - exec_wall, 0.0)
         stats.device_seconds = model_device_seconds(
             stats.bytes_in, stats.bytes_out, self.geom)
-        stats.sort_seconds = self._sort_model(sig)
+        stats.sort_seconds = self._sort_model(launch)
         return out, stats
 
     def build_image(self, keys, meta, vals, n_blocks=None) -> SSTImage:

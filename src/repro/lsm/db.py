@@ -794,26 +794,31 @@ class LsmDB:
 
     def _search_version(self, version, key: bytes,
                         opts: ReadOptions | None = None):
+        if len(key) > self.geom.key_bytes:
+            return None     # longer than any key put accepts
+        # the key's filter hash pair: the first filter check fills it,
+        # the tables after it reuse it
+        hashes: list[int] = []
         # L0: overlapping files, newest first
         for fm in sorted(version.levels[0], key=lambda f: -f.file_no):
             if fm.smallest <= key <= fm.largest:
-                found, value = self._table_get(fm, key, opts)
+                found, value = self._table_get(fm, key, opts, hashes)
                 if found:
                     return value
         # deeper levels: disjoint ranges
         for level in range(1, len(version.levels)):
             for fm in version.levels[level]:
                 if fm.smallest <= key <= fm.largest:
-                    found, value = self._table_get(fm, key, opts)
+                    found, value = self._table_get(fm, key, opts, hashes)
                     if found:
                         return value
                     break
         return None
 
     def _table_get(self, fm: FileMeta, key: bytes,
-                   opts: ReadOptions | None = None):
+                   opts: ReadOptions | None, hashes: list[int]):
         found, value, pruned = self.cache.reader(fm, self.geom).probe(
-            key, opts, tracer=self.tracer)
+            key, opts, tracer=self.tracer, hashes=hashes)
         if pruned:
             self._c["bloom_negative_skips"].inc()
         return found, value

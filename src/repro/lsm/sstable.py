@@ -388,17 +388,22 @@ class TableReader:
             return DEFAULT_READ_OPTIONS
         return opts
 
-    def probe(self, key: bytes, opts=None, *, tracer=NULL_TRACER
+    def probe(self, key: bytes, opts=None, *, tracer=NULL_TRACER,
+              hashes: list[int] | None = None
               ) -> tuple[bool, bytes | None, bool]:
         """``(found, value|None, bloom_pruned)``: the tombstone-aware
         lookup.  ``found=True, value=None`` means a tombstone shadows the
         key; ``bloom_pruned=True`` means the filter proved absence without
-        decoding a block.  With ``tracer`` enabled, the table's first
-        read (the whole file, its CRC and the blocks' first keys) is one
-        ``get.table_load`` span, and a block-cache miss one
-        ``get.block_load`` span (the block's decode, with its CRC check
-        when ``opts.verify_crc``); a cached table or block records
-        nothing.
+        decoding a block.  ``hashes`` holds the key's filter hash pair
+        (``cpu_engine.bloom_hashes_of``) once a filter check computed it:
+        a get over several tables passes one list, so the key is hashed
+        at most once, and only when a filter is checked.  With
+        ``tracer`` enabled, the table's first read (the whole file, its
+        CRC and the blocks' first keys) is one ``get.table_load`` span, a
+        filter check one ``get.filter`` span (arg ``pruned``), and a
+        block-cache miss one ``get.block_load`` span (the block's decode,
+        with its CRC check when ``opts.verify_crc``); a cached table or
+        block records nothing.
 
         Searching ``keys_packed`` with the plain user key is exact:
         numpy ``S`` comparisons zero-pad the scalar to the item width,
@@ -423,12 +428,18 @@ class TableReader:
             # host bloom probe costs more than searching a cached block
             row = self.bloom_row(b)
             if row is not None:
-                probe_lanes = formats.pack_key_bytes(key,
-                                                     self.geom.key_bytes)
-                hit = ce.np_bloom_query(row[None],
-                                        probe_lanes[None, None, :],
-                                        self.geom.bloom_probes)
-                if not bool(hit[0, 0]):
+                t0 = time.perf_counter_ns() if tracer.enabled else 0
+                if hashes is None:
+                    hashes = []
+                if not hashes:
+                    hashes += ce.bloom_hashes_of(key, self.geom.key_bytes)
+                hit = ce.bloom_row_may_contain(row, *hashes,
+                                               self.geom.bloom_probes)
+                if tracer.enabled:
+                    tracer.complete("get.filter", t0,
+                                    time.perf_counter_ns() - t0,
+                                    args={"pruned": not hit})
+                if not hit:
                     return False, None, True
             t0 = time.perf_counter_ns() if tracer.enabled else 0
             blk = self.decode_block(b, fill_cache=opts.fill_cache,

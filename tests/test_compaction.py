@@ -256,20 +256,19 @@ def test_jobs_of_one_slot_class_reuse_one_program():
     8 runs of at most 4 blocks), has the same signature, counts as a hit
     and adds no jit cache entry: the 14- and 15-run L1->L2 jobs of a
     store at the paper's geometry, scaled down."""
-    from repro.core.scheduler import batch_signature
     rng = np.random.default_rng(11)
     first = _random_run_images(rng, (64, 50, 64, 33, 64))
     second = _random_run_images(rng, (64,) * 7)
-    sigs = [batch_signature([im.keys.shape[0] for im in job], True)
+    sigs = [offload.run_slots([im.keys.shape[0] for im in job])
             for job in (first, second)]
-    assert sigs[0] == sigs[1] == (8, 4, True)
+    assert sigs[0] == sigs[1] == (8, 4)
     eng = _device_engine()
     eng.compact(first, bottom_level=True)
     entries = compaction.compact._cache_size()
     eng.compact(second, bottom_level=True)
     assert compaction.compact._cache_size() == entries
     assert (eng.jit_bucket_misses, eng.jit_bucket_hits) == (1, 1)
-    assert eng.jit_signature_counts == {("one", 8, 4, True): 2}
+    assert eng.jit_signature_counts == {("one", 8, 4): 2}
 
 
 @pytest.mark.parametrize("sizes", [(32, 32, 32, 32, 32), (32, 32, 40)],
@@ -282,8 +281,110 @@ def test_job_crossing_a_slot_class_gets_a_new_signature(sizes):
     eng.compact(base)
     eng.compact(other)
     assert (eng.jit_bucket_misses, eng.jit_bucket_hits) == (2, 0)
-    assert ("one", 4, 2, False) in eng.jit_signature_counts
+    assert ("one", 4, 2) in eng.jit_signature_counts
     assert len(eng.jit_signature_counts) == 2
+
+
+GEOM_SST = SSTGeometry(key_bytes=16, value_bytes=32, block_bytes=1024,
+                       sst_bytes=8192, bloom_granularity="sst")
+
+
+@pytest.mark.parametrize("geom,bottom", [
+    (GEOM, False), (GEOM, True), (GEOM_SST, False)],
+    ids=["block", "block_bottom", "sst"])
+def test_uncompiled_class_runs_in_a_wider_compiled_one(geom, bottom):
+    """A job of class (4, 4) after a job of class (8, 4), twice its
+    blocks, runs in the (8, 4) program: no new jit cache entry, and its
+    output, cut to its own class, is byte for byte what its own class
+    gives, and what the CPU engine gives."""
+    from repro.lsm import sstable
+    from repro.lsm.cpu_engine import CpuCompactionEngine, \
+        DeviceCompactionEngine
+    rng = np.random.default_rng(13)
+    wide = _random_run_images(rng, (64,) * 5)
+    narrow = _random_run_images(rng, (64, 20, 50))
+    assert offload.run_slots([im.keys.shape[0] for im in narrow]) == (4, 4)
+    eng = DeviceCompactionEngine(geom)
+    eng.compact(wide)
+    entries = compaction.compact._cache_size()
+    out, st = eng.compact(narrow, bottom_level=bottom)
+    assert compaction.compact._cache_size() == entries
+    assert eng.class_reuses == 1
+    assert eng.jit_signature_counts == {("one", 8, 4): 2}
+    own, st_own = DeviceCompactionEngine(geom).compact(
+        narrow, bottom_level=bottom)
+    for field, a, b in zip(out._fields, out, own):
+        np.testing.assert_array_equal(a, b, err_msg=f"field {field}")
+    fields = ("n_input", "n_live", "n_dropped", "crc_ok", "bytes_in",
+              "bytes_out")
+    assert [getattr(st, f) for f in fields] == \
+        [getattr(st_own, f) for f in fields]
+    if geom is GEOM:
+        cpu, _ = CpuCompactionEngine(geom).compact(narrow,
+                                                   bottom_level=bottom)
+        for field, a, b in zip(out._fields, sstable.trim_image(out),
+                               sstable.trim_image(cpu)):
+            np.testing.assert_array_equal(a, b, err_msg=f"field {field}")
+
+
+def test_class_more_than_twice_narrower_compiles_its_own():
+    """A wider launched class is reused only up to twice the job's
+    blocks: a (4, 2) job after an (8, 4) job, a quarter of its blocks,
+    compiles its own class, and a (2, 2) job after it runs in that."""
+    from repro.lsm.cpu_engine import MAX_CLASS_WIDENING, \
+        DeviceCompactionEngine
+    assert MAX_CLASS_WIDENING == 2
+    rng = np.random.default_rng(16)
+    eng = DeviceCompactionEngine(GEOM)
+    eng.compact(_random_run_images(rng, (64,) * 5))
+    eng.compact(_random_run_images(rng, (32, 20, 32)))    # class (4, 2)
+    assert eng.class_reuses == 0
+    assert eng.jit_bucket_misses == 2
+    entries = compaction.compact._cache_size()
+    eng.compact(_random_run_images(rng, (30, 20)))        # class (2, 2)
+    assert compaction.compact._cache_size() == entries
+    assert eng.class_reuses == 1
+    assert eng.jit_signature_counts == {("one", 8, 4): 1, ("one", 4, 2): 2}
+
+
+def test_wider_class_with_other_filter_groups_is_not_reused():
+    """At sst granularity a class of fewer entries than an SST holds
+    builds one filter over the whole image, so a wider class would write
+    another filter: the job compiles its own class."""
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+    rng = np.random.default_rng(14)
+    eng = DeviceCompactionEngine(GEOM_SST)
+    eng.compact(_random_run_images(rng, (64,) * 5))
+    eng.compact(_random_run_images(rng, (30, 20)))      # class (2, 2)
+    assert eng.class_reuses == 0
+    assert set(eng.jit_signature_counts) == {("one", 8, 4), ("one", 2, 2)}
+
+
+def test_bottom_and_non_bottom_jobs_share_one_program():
+    """The bottom level is a traced flag: a bottom job after a non-bottom
+    job of one class adds no jit cache entry, and tombstones are dropped
+    at the bottom only, as the CPU engine drops them."""
+    from repro.lsm import sstable
+    from repro.lsm.cpu_engine import CpuCompactionEngine
+    rng = np.random.default_rng(15)
+    images = _random_run_images(rng, (60, 45, 80))
+    eng = _device_engine()
+    outs = [eng.compact(images, bottom_level=False)]
+    entries = compaction.compact._cache_size()
+    outs.append(eng.compact(images, bottom_level=True))
+    assert compaction.compact._cache_size() == entries
+    assert eng.jit_signature_counts == {("one", 4, 8): 2}
+    assert eng.class_reuses == 0
+    kept = [[is_value for _, _, is_value, _ in read_entries(o)]
+            for o, _ in outs]
+    assert not all(kept[0]) and all(kept[1])
+    assert outs[1][1].n_dropped > outs[0][1].n_dropped
+    for bottom, (out, _) in zip((False, True), outs):
+        cpu, _ = CpuCompactionEngine(GEOM).compact(images,
+                                                   bottom_level=bottom)
+        for field, a, b in zip(out._fields, sstable.trim_image(out),
+                               sstable.trim_image(cpu)):
+            np.testing.assert_array_equal(a, b, err_msg=f"field {field}")
 
 
 @pytest.mark.parametrize("sizes,bottom", [

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from repro.core.formats import SSTGeometry
-from repro.core.scheduler import SchedulerConfig, batch_signature
+from repro.core.offload import run_slots
+from repro.core.scheduler import SchedulerConfig
 from repro.lsm import faults
 from repro.lsm.db import DBConfig, LsmDB
 from repro.lsm.sharded import (ShardedDB, boundaries_from_sample,
@@ -129,18 +130,20 @@ def test_sharded_matches_single_db_oracle(tmp_path, shards):
 
 
 def _sst_writer(eng, tmp_path, rng):
-    """``make_sst(prefix, n)``: write an SST of ``n`` sorted keys under
-    ``prefix`` and return its path."""
+    """``make_sst(prefix, n, dead=0)``: write an SST of ``n`` sorted keys
+    under ``prefix`` (every ``dead``-th a tombstone) and return its
+    path."""
     from repro.core import formats
     from repro.lsm import sstable
     no = [0]
 
-    def make_sst(prefix, n):
+    def make_sst(prefix, n, dead=0):
         keys = sorted(prefix + b"key%04d" % int(x)
                       for x in rng.choice(2000, n, replace=False))
         karr = np.stack([formats.pack_key_bytes(k, GEOM.key_bytes)
                          for k in keys])
-        meta = np.array([(i + 1) << 1 | 1 for i in range(n)], np.uint32)
+        meta = np.array([(i + 1) << 1 | bool(not dead or i % dead)
+                         for i in range(n)], np.uint32)
         vals = np.stack([formats.pack_value_bytes(b"v%d" % i,
                                                   GEOM.value_bytes)
                          for i in range(n)])
@@ -153,19 +156,18 @@ def _sst_writer(eng, tmp_path, rng):
 
 
 def test_batch_signature_is_the_run_slot_class():
-    """(slots, slot blocks, bottom level): a power-of-two run count and
-    a power-of-two block count that holds the largest run -- the jobs of
-    a store at the paper's geometry (4 MiB SSTs of 1,024 blocks)."""
+    """(slots, slot blocks): a power-of-two run count and a power-of-two
+    block count that holds the largest run -- the jobs of a store at the
+    paper's geometry (4 MiB SSTs of 1,024 blocks).  The bottom level is
+    a traced flag, not part of the signature."""
     l0 = [250, 253, 253, 253, 1024, 788]
-    assert batch_signature(l0, False) == (8, 1024, False)
-    assert batch_signature([253] * 4 + [1024, 770], False) == \
-        (8, 1024, False)
-    assert batch_signature([1024] * 14, True) == \
-        batch_signature([1024] * 15, True) == (16, 1024, True)
-    assert batch_signature([1024] * 17, True) == (32, 1024, True)
-    assert batch_signature([256] * 4, False) == (4, 256, False)
-    assert batch_signature([256, 257], False) == (2, 512, False)
-    assert batch_signature([7], True) == (1, 8, True)
+    assert run_slots(l0) == (8, 1024)
+    assert run_slots([253] * 4 + [1024, 770]) == (8, 1024)
+    assert run_slots([1024] * 14) == run_slots([1024] * 15) == (16, 1024)
+    assert run_slots([1024] * 17) == (32, 1024)
+    assert run_slots([256] * 4) == (4, 256)
+    assert run_slots([256, 257]) == (2, 512)
+    assert run_slots([7]) == (1, 8)
 
 
 def test_compact_many_bit_identical_and_batched(tmp_path):
@@ -182,10 +184,8 @@ def test_compact_many_bit_identical_and_batched(tmp_path):
     jobs = [([make_sst(b"a", 25), make_sst(b"a", 30)], False),
             ([make_sst(b"b", 28), make_sst(b"b", 24)], False),
             ([make_sst(b"c", 120), make_sst(b"c", 110)], True)]
-    sigs = [batch_signature([max(1, -(-n // GEOM.block_kvs))
-                             for n in (25, 30)], False),
-            batch_signature([max(1, -(-n // GEOM.block_kvs))
-                             for n in (28, 24)], False)]
+    sigs = [run_slots([max(1, -(-n // GEOM.block_kvs)) for n in (25, 30)]),
+            run_slots([max(1, -(-n // GEOM.block_kvs)) for n in (28, 24)])]
     assert sigs[0] == sigs[1]   # the two small jobs really share a bucket
 
     seq = [eng.compact_paths(p, bottom_level=b) for p, b in jobs]
@@ -246,10 +246,68 @@ def test_compact_many_stacks_jobs_of_one_slot_class(tmp_path):
     launches0 = eng.batch_launches
     batched = eng.compact_many(jobs)
     assert eng.batch_launches == launches0 + 1
-    assert ("batch", 2, 4, 4, False) in eng.jit_signature_counts
+    assert ("batch", 2, 4, 4) in eng.jit_signature_counts
     for (o1, s1), (o2, s2) in zip(seq, batched):
         assert s2.batched and (s1.n_live, s1.bytes_out) == \
             (s2.n_live, s2.bytes_out)
+        for a, b, name in zip(o1, o2, o1._fields):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_compact_many_stacks_bottom_and_non_bottom_jobs(tmp_path):
+    """The bottom level is a per-job flag of the stacked launch: a bottom
+    job and a non-bottom job of one run-slot class ride one launch, each
+    bit-identical to its single-job run."""
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+
+    eng = DeviceCompactionEngine(GEOM)
+    make_sst = _sst_writer(eng, tmp_path, np.random.default_rng(10))
+    jobs = [([make_sst(b"a", 60, dead=3), make_sst(b"a", 30)], False),
+            ([make_sst(b"a", 50, dead=3), make_sst(b"b", 64)], True)]
+    seq = [eng.compact_paths(p, bottom_level=b) for p, b in jobs]
+    # the bottom job drops its tombstones, the other keeps them
+    out0 = seq[0][0]
+    valid0 = np.arange(GEOM.block_kvs) < np.asarray(out0.nvalid)[:, None]
+    assert np.any((np.asarray(out0.meta)[valid0] & 1) == 0)
+    assert seq[1][1].n_dropped >= 50 // 3 and seq[1][1].n_input == 114
+    launches0 = eng.batch_launches
+    batched = eng.compact_many(jobs)
+    assert eng.batch_launches == launches0 + 1
+    assert ("batch", 2, 2, 4) in eng.jit_signature_counts
+    for (o1, s1), (o2, s2) in zip(seq, batched):
+        assert s2.batched and (s1.n_live, s1.n_dropped, s1.bytes_out) == \
+            (s2.n_live, s2.n_dropped, s2.bytes_out)
+        for a, b, name in zip(o1, o2, o1._fields):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_compact_many_launches_a_singleton_in_its_staged_class(tmp_path):
+    """Every group of a call is laid out before the first launch: a
+    (4, 4) job behind an (8, 4) job launches the (4, 4) image it was
+    laid out in and is counted under that class, with no class reuse,
+    bit-identical to its single-job run."""
+    from repro.lsm.cpu_engine import DeviceCompactionEngine
+    from repro.obs import Tracer
+
+    tracer = Tracer()
+    eng = DeviceCompactionEngine(GEOM, tracer=tracer)
+    make_sst = _sst_writer(eng, tmp_path, np.random.default_rng(12))
+    jobs = [([make_sst(b"a", 64) for _ in range(5)], False),
+            ([make_sst(b"b", 64), make_sst(b"b", 30), make_sst(b"b", 50)],
+             True)]
+    out = eng.compact_many(jobs)
+    assert eng.jit_signature_counts == {("one", 8, 4): 1, ("one", 4, 4): 1}
+    assert (eng.jit_bucket_misses, eng.class_reuses) == (2, 0)
+    spans = [e["args"] for e in tracer.to_chrome()["traceEvents"]
+             if e["ph"] == "X" and e["name"] == "compact.execute"]
+    assert [(a["slots"], a["slot_blocks"], a["sig_hit"]) for a in spans] \
+        == [(8, 4, False), (4, 4, False)]
+    assert not any("reused_class" in a for a in spans)
+    for (paths, bottom), (o2, s2) in zip(jobs, out):
+        o1, s1 = DeviceCompactionEngine(GEOM).compact_paths(
+            paths, bottom_level=bottom)
+        assert (s1.n_live, s1.n_dropped, s1.bytes_out) == \
+            (s2.n_live, s2.n_dropped, s2.bytes_out)
         for a, b, name in zip(o1, o2, o1._fields):
             np.testing.assert_array_equal(a, b, err_msg=name)
 
@@ -408,6 +466,8 @@ def test_one_shard_bg_error_isolated_and_resumable(
     serving reads and writes, and ``ShardedDB.resume()`` brings the
     failed shard back without losing its acknowledged (WAL-held) rows."""
     path = str(tmp_path / "sh")
+    # fire counts outlive clear(): count this test's fires only
+    fired0 = faults.FAILPOINTS.fired("flush.build")
     # async mode: flushes run on the background executor, so a failure
     # lands as a classified bg_error (the sync path surfaces foreground
     # errors directly to the caller and never halts)
@@ -424,7 +484,7 @@ def test_one_shard_bg_error_isolated_and_resumable(
                 db.put(b"a%04d" % i, b"v%04d" % i)
             db.shards[0].flush()
             db.shards[0].wait_idle()
-        assert faults.FAILPOINTS.fired("flush.build") == 1
+        assert faults.FAILPOINTS.fired("flush.build") - fired0 == 1
         assert db.shards[0]._bg_error is not None
         assert db.shards[0]._bg_error.severity == "hard"
 
