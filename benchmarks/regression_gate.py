@@ -123,7 +123,7 @@ def _serve_rows() -> dict:
 
     ``serve.resume.p99_cpu_smoke``: batched ``load_many`` tail latency
     per session on the LSM session-store backend -- the two-wave
-    multi_get resume path behind ``ServeEngine.load_sessions``.  The
+    multi_get resume path of ``LsmSessionStore.load_many``.  The
     row also enforces correctness directly: the batched states must be
     bit-identical to the scalar ``load`` loop (and to what was saved),
     or the emit aborts -- a fast wrong resume is not a benchmark."""

@@ -2,8 +2,7 @@
 resume latency, and drop/overwrite churn against every SessionStore
 backend.
 
-Grown out of ``examples/serve_demo.py``: instead of one model session
-this drives N synthetic KV-cache-shaped sessions through the store and
+Drives N synthetic KV-cache-shaped sessions through the store and
 measures the serving-side contract end to end:
 
 1. **page-out** -- save N sessions, then overwrite rounds until the LSM

@@ -1,9 +1,9 @@
 """SessionStore: pluggable paging backends for serving KV-cache sessions.
 
-A *session* is the resumable state of one conversation -- the pytree
-``(cache, pos)`` produced by ``ServeEngine.generate``.  This module owns
-how sessions are serialized and where they live; the engine only calls
-the small ``SessionStore`` protocol:
+A *session* is the resumable state of one conversation -- a pytree such
+as a decoder's ``(cache, pos)``.  This module owns how sessions are
+serialized and where they live; callers only use the small
+``SessionStore`` protocol:
 
   * ``save(session, state) -> int``   -- persist (atomic per backend)
   * ``load(session) -> state``        -- raise ``KeyError`` if absent
@@ -49,8 +49,7 @@ Serialization needs the pytree *structure* to rebuild states; leaf
 shapes/dtypes travel in the stored metadata, but the treedef does not
 serialize portably.  Each store therefore takes a ``template``: a
 structurally-matching pytree, or a zero-arg callable returning one
-(evaluated lazily, once).  ``ServeEngine`` supplies its own template,
-so users of the engine never see this detail.
+(evaluated lazily, once).
 """
 
 from __future__ import annotations
@@ -101,7 +100,7 @@ def decode_state(meta: bytes, raw: bytes, template):
 
 @runtime_checkable
 class SessionStore(Protocol):
-    """What ``ServeEngine`` requires of a paging backend."""
+    """What every paging backend provides."""
 
     def save(self, session: str, state) -> int: ...
 

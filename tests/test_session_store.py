@@ -57,7 +57,7 @@ def assert_state_equal(a, b):
         assert xa.tobytes() == ya.tobytes()
 
 
-def _backends(tmp_path):
+def _backends(tmp_path, template=template):
     """(name, store, closer) for every backend cell."""
     out = [("memory", MemorySessionStore(template), lambda: None)]
     for mode in ("sync", "async"):
@@ -91,6 +91,49 @@ def test_load_many_bit_identical_to_scalar_on_every_backend(tmp_path):
         for b, sc, want in zip(batched, scalar, (states[n] for n in names)):
             assert_state_equal(b, sc)
             assert_state_equal(b, want)
+        close()
+
+
+def _bf16_layer_cache(rng, i):
+    """A decoder's cache: a (k, v) pair of bf16 [batch, heads, time, dim]
+    arrays per layer, and the int32 position of each sequence."""
+    kv = lambda: jnp.asarray(rng.standard_normal((2, 2, 24, 16)),
+                             jnp.bfloat16)
+    return {"cache": tuple((kv(), kv()) for _ in range(3)),
+            "pos": jnp.asarray([i, i + 7], jnp.int32)}
+
+
+def _f16_cache(rng, i):
+    return {"kv": jnp.asarray(rng.standard_normal((4, 33, 8)), jnp.float16)}
+
+
+def _int8_scaled_cache(rng, i):
+    """Quantized cache: int8 values with one f32 scale per row."""
+    return {"q": jnp.asarray(rng.integers(-128, 128, (6, 40)), jnp.int8),
+            "scale": jnp.asarray(rng.random((6, 1)) + 0.01, jnp.float32)}
+
+
+def _scalar_leaf_cache(rng, i):
+    return {"kv": jnp.asarray(rng.standard_normal((5, 9)), jnp.float32),
+            "pos": jnp.asarray(i, jnp.int32)}
+
+
+@pytest.mark.parametrize("make", [_bf16_layer_cache, _f16_cache,
+                                  _int8_scaled_cache, _scalar_leaf_cache])
+def test_kv_cache_shaped_state_roundtrips_on_every_backend(tmp_path, make):
+    """Cache pytrees of the dtypes and nestings a decoder pages: every
+    leaf comes back with its dtype, shape and bytes, from ``load`` and
+    ``load_many`` alike."""
+    rng = np.random.default_rng(17)
+    states = {f"s{i}": make(rng, i) for i in range(5)}
+    names = sorted(states)
+    like = jax.tree.map(jnp.zeros_like, states["s0"])
+    for name, store, close in _backends(tmp_path, like):
+        for s, st in states.items():
+            store.save(s, st)
+        for s, got in zip(names, store.load_many(names)):
+            assert_state_equal(got, states[s])
+            assert_state_equal(store.load(s), states[s])
         close()
 
 
